@@ -1,26 +1,32 @@
 """The paper's own workload: full MobileNet V1/V2 bodies through the
 whole-network chain engine (NetworkSpec -> NetworkPlan -> ONE jitted
 execute_network call, DESIGN.md §7) with per-segment mixed-precision
-streaming.
+streaming, on the compiled Pallas kernels.
 
   PYTHONPATH=src python examples/mobilenet_inference.py \
-      [--pallas] [--res N] [--dtype fp32|bf16] [--arch v1|v2|both] [--verify]
+      [--interpret] [--res N] [--dtype fp32|bf16] [--arch v1|v2|both] \
+      [--verify] [--fault-inject POINTS]
+
+Without --interpret the kernels compile for the TPU and the run fails on
+any other backend.  --interpret runs the same kernels in Pallas interpret
+mode on the CPU (slow; a rehearsal, not a measurement).
 
 --dtype bf16 streams activations and weights as bf16 while every kernel
 accumulates in fp32 (the DtypePolicy of DESIGN.md §7) — the modeled HBM
 traffic halves, which is the whole game for these memory-bound ops.
---pallas runs the Pallas kernels in interpret mode (slow, CPU) instead of
-the XLA path, and cross-checks outputs.
 --res N runs at an NxN body input instead of 112x112 (a 224 image after
-the stem).  CI smokes --res 16 (fp32, interpret) and --res 32 --dtype bf16.
+the stem).  CI smokes --interpret at --res 16 (fp32) and --res 32 (bf16).
 --fused is accepted for compatibility; fusion is a planner decision now
 and always on (KernelPolicy(fused=False) remains the opt-out).
---fault-inject POINTS arms the runtime fault-injection harness (DESIGN.md
-§9) at the named points (comma-separated ``point[:times]``, persistent by
-default) before executing: the degradation ladder recovers, the oracle
-parity assertion still holds, and the fallback telemetry is printed at the
-end.  The quarantine store defaults to artifacts/runtime/quarantine.json
-for this mode (override with $REPRO_QUARANTINE).
+
+Every run is checked against the fp32 per-block XLA oracle at "highest"
+matmul precision.  A kernel the compiler refuses raises (on_failure=
+"raise"), and the run fails if the runtime telemetry records any fallback
+or quarantine hit.  --fault-inject POINTS instead arms the runtime
+fault-injection harness (DESIGN.md §9) at the named points (comma-separated
+``point[:times]``) under the degradation ladder: the oracle parity still
+holds and exactly the injected fallbacks are recorded.  Either way the
+quarantine store is a fresh artifacts/runtime/quarantine.json.
 """
 import os
 import sys
@@ -33,19 +39,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import KernelPolicy, chain, network
+from repro.core import KernelPolicy, network
 from repro.core import intensity as it
 from repro.kernels.policy import DtypePolicy
+from repro.runtime import faultinject, telemetry
+from repro.runtime.compile_cache import enable_compile_cache
 
-#: bf16-vs-fp32 network tolerance (documented in DESIGN.md §7 and asserted
-#: by tests/test_network.py): one bf16 rounding per streamed operand per
-#: block, compounded over 13-17 blocks, lands ~1e-2; 5e-2 is the gate.
-BF16_REL_TOL = 5e-2
+QUARANTINE = os.path.join("artifacts", "runtime", "quarantine.json")
 
 
 def _policy(args, dtype_policy):
-    return KernelPolicy(impl="pallas" if args.pallas else "xla",
-                        interpret=args.pallas, dtype_policy=dtype_policy)
+    return KernelPolicy(
+        impl="pallas", interpret=args.interpret, dtype_policy=dtype_policy,
+        on_failure="degrade" if args.fault_inject else "raise")
 
 
 def run_network(name, net, args):
@@ -70,7 +76,7 @@ def run_network(name, net, args):
         analysis.verify_or_raise(report)
     histo = ",".join(f"{k}:{v}"
                      for k, v in sorted(nplan.segment_histogram().items()))
-    print(f"\n{name} body @{res}x{res} ({args.dtype}, {pol.impl}"
+    print(f"\n{name} body @{res}x{res} ({args.dtype}, pallas"
           f"{' interpret' if pol.interpret else ''}):")
     print(f"  plan: {net.n_blocks} blocks -> {nplan.n_kernel_passes} kernel "
           f"passes ({histo}), fully fused: {nplan.fully_fused}")
@@ -80,9 +86,7 @@ def run_network(name, net, args):
                                                             DtypePolicy()))
     t32 = it.network_traffic(net, n32)
     nunf = network.plan_network(
-        net, x.shape,
-        policy=KernelPolicy(impl=pol.impl, interpret=pol.interpret,
-                            fused=False))
+        net, x.shape, policy=KernelPolicy(impl="pallas", fused=False))
     tunf = it.network_traffic(net, nunf)
     print(f"  modeled HBM: {t.bytes_hbm/1e6:.2f} MB "
           f"(fp32 fused {t32.bytes_hbm/1e6:.2f} MB, per-block unfused "
@@ -95,29 +99,23 @@ def run_network(name, net, args):
     y = network.execute_network(net, params, x, policy=pol,
                                 network_plan=nplan_arg)
     jax.block_until_ready(y)
-    reps = 2 if args.pallas else 10
+    reps = 2 if args.interpret else 10
     t0 = time.perf_counter()
     for _ in range(reps):
         y = network.execute_network(net, params, x, policy=pol,
                                     network_plan=nplan_arg)
     jax.block_until_ready(y)
     ms = (time.perf_counter() - t0) / reps * 1e3
-    print(f"  end-to-end: {ms:.2f} ms/image -> features {y.shape} {y.dtype}")
+    print(f"  wall {ms:.2f} ms/image on {jax.devices()[0].platform} (not a "
+          f"benchmark) -> features {y.shape} {y.dtype}")
 
-    # Parity vs the fp32 per-block oracle (XLA, native dtype, fresh fp32
-    # weights — the pre-network-engine execution path).  Injection is
-    # suppressed around it: the yardstick itself must not degrade.
-    from repro.runtime import faultinject
-    p32 = network.init_network(jax.random.PRNGKey(0), net)
-    oracle = KernelPolicy(impl="xla", on_failure="raise")
-    with faultinject.suppressed():
-        ref = x
-        for spec, p in zip(net.blocks, p32):
-            ref = chain.execute(spec, p, ref, policy=oracle)
+    ref = network.reference_network(
+        net, network.init_network(jax.random.PRNGKey(0), net), x)
     ref = np.asarray(ref, np.float32)
     got = np.asarray(y, np.float32)
     rel = float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-30))
-    tol = BF16_REL_TOL if args.dtype == "bf16" else 1e-5
+    tol = (network.BF16_REL_TOL if args.dtype == "bf16"
+           else network.FP32_REL_TOL)
     print(f"  vs fp32 per-block oracle: max rel err {rel:.2e} "
           f"(tol {tol:g})")
     assert rel < tol, f"{name}: {rel} >= {tol}"
@@ -127,9 +125,9 @@ def run_network(name, net, args):
 def main():
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--pallas", action="store_true",
-                    help="run the Pallas kernels in interpret mode (slow, "
-                         "CPU) and cross-check against the XLA path")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in interpret mode on the "
+                         "CPU (slow; a rehearsal of the TPU run)")
     ap.add_argument("--fused", action="store_true",
                     help="(compat no-op) fusion is a planner decision and "
                          "always on; KernelPolicy(fused=False) opts out")
@@ -147,14 +145,20 @@ def main():
     ap.add_argument("--fault-inject", default=None, metavar="POINTS",
                     help="arm runtime fault-injection points "
                          "(comma-separated point[:times], DESIGN.md §9) "
-                         "and print the fallback telemetry")
+                         "under the degradation ladder")
     args = ap.parse_args()
 
+    backend = jax.default_backend()
+    if not args.interpret and backend != "tpu":
+        sys.exit(f"compiled Pallas kernels need a TPU, found {backend!r}; "
+                 "pass --interpret to rehearse on the CPU")
+    if not args.interpret:
+        enable_compile_cache()
+    if os.path.exists(QUARANTINE):
+        os.remove(QUARANTINE)
+    os.environ["REPRO_QUARANTINE"] = QUARANTINE
+
     if args.fault_inject:
-        os.environ.setdefault(
-            "REPRO_QUARANTINE",
-            os.path.join("artifacts", "runtime", "quarantine.json"))
-        from repro.runtime import faultinject
         points = faultinject.arm_from_spec(args.fault_inject)
         print(f"fault injection armed: {', '.join(points)}")
 
@@ -166,16 +170,18 @@ def main():
     for name, net in nets:
         run_network(name, net, args)
 
+    rep = telemetry.runtime_report()
+    print(f"\nruntime telemetry: {rep['fallbacks']} fallbacks "
+          f"({rep['injected_fallbacks']} injected), "
+          f"{rep['recoveries']} recoveries, "
+          f"{rep['quarantine_hits']} quarantine hits"
+          + (f"; fired: {faultinject.fired_counts()}"
+             if args.fault_inject else ""))
     if args.fault_inject:
-        from repro.runtime import faultinject, telemetry
-        rep = telemetry.runtime_report()
-        print(f"\nruntime telemetry: {rep['fallbacks']} fallbacks "
-              f"({rep['injected_fallbacks']} injected), "
-              f"{rep['recoveries']} recoveries, "
-              f"{rep['quarantine_hits']} quarantine hits; fired: "
-              f"{faultinject.fired_counts()}")
         assert rep["fallbacks"] == rep["injected_fallbacks"], rep
         faultinject.disarm_all()
+    else:
+        assert rep["fallbacks"] == 0 and rep["quarantine_hits"] == 0, rep
 
     print("\nper-layer AI bounds (paper's analysis, DESIGN.md §2): "
           f"DW ours {it.t_ours_dw_asymptotic(3, 3):.3f} vs TF-Lite "

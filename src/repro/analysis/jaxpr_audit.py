@@ -43,7 +43,7 @@ from repro.kernels.policy import KernelPolicy
 #: Primitives a fully-fused chain may run OUTSIDE the kernel: data prep for
 #: the one kernel pass (padding, layout, casts) — never compute.
 ALLOWED_OUTSIDE = frozenset({
-    "pallas_call", "pjit", "closed_call", "custom_jvp_call",
+    "pallas_call", "jit", "closed_call", "custom_jvp_call",
     "custom_vjp_call", "convert_element_type", "pad", "slice",
     "dynamic_slice", "reshape", "broadcast_in_dim", "transpose", "squeeze",
     "concatenate", "iota", "copy",
@@ -207,10 +207,6 @@ def lint_chain_jaxpr(spec, chain_plan: ChainPlan, x_shape: Sequence[int],
     diags = audit_casts(jaxpr, allowed, label)
     diags.extend(audit_accumulation(jaxpr, label))
     if policy.resolved() == "pallas":
-        # se lowers to TWO pwconv passes (reduce + expand GEMMs); mb lowers
-        # to the XLA convolution on every impl (ZERO Pallas passes)
-        n_expected = sum({"se": 2, "mb": 0}.get(s.kind, 1)
-                         for s in chain_plan.segments)
-        diags.extend(audit_passes(jaxpr, n_expected,
+        diags.extend(audit_passes(jaxpr, chain_plan.n_pallas_calls,
                                   chain_plan.fully_fused, label))
     return diags

@@ -3,10 +3,9 @@
 Mosaic lays VMEM out in (sublane, lane) tiles: the minor dimension in
 128-lane vectors, the second-minor in sublanes whose count depends on the
 element width (fp32 8, bf16 16, int8/fp8 32).  Interpret-mode parity tests
-(the whole test suite on CPU) cannot see these constraints — ROADMAP open
-item 1 is precisely that the in-kernel collapsing reshapes and the
-``pl.unblocked`` row offsets are unvalidated against them.  This pass
-encodes the statically checkable half as lint rules:
+(the whole test suite on CPU) cannot see these constraints; the v5e compile
+guard (``tests/test_tpu_compile.py``) can, at the cost of a compile.  This
+pass encodes the statically checkable half as lint rules:
 
 * MC201 (warning) — a block's minor dimension is not a multiple of 128
   lanes (legal, but pads every vector: lane utilization cost).
@@ -16,9 +15,12 @@ encodes the statically checkable half as lint rules:
   (``(Sh, Wo, Cb) -> (Sh·Wo, Cb)``) whose collapsed second-minor is not
   sublane-aligned, or that changes the minor dimension — the shapes Mosaic
   may refuse or spill on.
-* MC204 — ``pl.unblocked`` element offsets: misaligned offsets in the
-  TILED (last two) dimensions are a warning; any unblocked use at all is
-  an info (the dynamic half of the ROADMAP item still needs hardware).
+* MC204 (error) — an element-offset window (``BlockRef.unblocked``) whose
+  offset in a TILED dimension is not PROVABLY a multiple of the tile (128
+  lanes minor, the sublane count second-minor).  Mosaic proves this over
+  the index map with the grid indices unknown and refuses the kernel when
+  it cannot ("Couldn't prove that tiling (128) divides index"): ``k * 32``
+  is refused even when ``k`` only ever takes the value 0.
 * MC205 (error) — an "arbitrary" (reduction) grid dimension that is not
   innermost: the revisiting-accumulator pattern every kernel here relies
   on requires reduction dims after all parallel dims.
@@ -28,6 +30,7 @@ The dtype->sublane table is :data:`SUBLANES`; rules receive the SAME
 """
 from __future__ import annotations
 
+import math
 from typing import List, Sequence, Tuple
 
 from repro.analysis.diagnostics import (ERROR, INFO, WARNING, Diagnostic)
@@ -89,40 +92,52 @@ def check_reshapes(reshapes: Sequence[Tuple[Tuple[int, ...],
     return diags
 
 
+class _GridIndex:
+    """A grid index as Mosaic sees it when it checks a window offset: an
+    unknown integer.  Arithmetic on it tracks the largest constant known to
+    divide the expression, which is all the proof can use."""
+
+    def __init__(self, divisor: int = 1):
+        self.divisor = divisor
+
+    def __add__(self, other):
+        return _GridIndex(math.gcd(self.divisor, _divisor(other)))
+
+    __radd__ = __add__
+
+    def __mul__(self, other):
+        if not isinstance(other, _GridIndex) and other == 0:
+            return 0
+        return _GridIndex(self.divisor * _divisor(other))
+
+    __rmul__ = __mul__
+
+
+def _divisor(v) -> int:
+    """Largest constant known to divide ``v`` (0 for a literal zero)."""
+    return v.divisor if isinstance(v, _GridIndex) else abs(int(v))
+
+
 def check_unblocked(model: KernelModel, segment: str = "",
                     ) -> List[Diagnostic]:
-    """MC204 for every ``pl.unblocked`` operand: evaluate the index map at
-    the grid origin and the last cell of each dimension, and flag element
-    offsets in the tiled (last two) dims that are off the tile grid."""
+    """MC204 for every element-offset operand: evaluate the index map over
+    unknown grid indices and require each tiled-dim offset to be provably
+    a multiple of its tile, as Mosaic does before it accepts the window."""
     diags: List[Diagnostic] = []
-    sub = _sublanes(4)  # offsets land in fp32-tiled VMEM windows
     for br in model.inputs:
         if not br.unblocked:
             continue
-        geo = f"{br.name} block={br.block_shape}"
-        diags.append(Diagnostic(
-            "MC204", INFO,
-            "unblocked (element-offset) indexing — statically bounds-"
-            "checked here (PL120); runtime Mosaic behavior still needs "
-            "hardware validation (ROADMAP)", segment, geo))
-        probes = [tuple(0 for _ in model.grid)]
-        for d, g in enumerate(model.grid):
-            probes.append(tuple(g - 1 if i == d else 0
-                                for i in range(len(model.grid))))
-        flagged = False
-        for idx in probes:
-            pos = br.index_map(*idx)
-            if len(pos) >= 1 and pos[-1] % LANES:
-                flagged = True
-            if len(pos) >= 2 and pos[-2] % sub:
-                flagged = True
-        if flagged:
-            diags.append(Diagnostic(
-                "MC204", WARNING,
-                "unblocked offsets in the tiled (last two) dims are not "
-                "tile-aligned", segment, geo,
-                "Mosaic must realign every fetch; prefer sublane-aligned "
-                "slab offsets"))
+        pos = br.index_map(*(_GridIndex() for _ in model.grid))
+        tiles = (("minor", LANES), ("second-minor", _sublanes(br.itemsize)))
+        for off, (dim, tile) in zip(reversed(pos), tiles):
+            if _divisor(off) % tile:
+                diags.append(Diagnostic(
+                    "MC204", ERROR,
+                    f"element offset in the {dim} dim is not provably a "
+                    f"multiple of its {tile}-element tile", segment,
+                    f"{br.name} block={br.block_shape}",
+                    "Mosaic refuses the window; make the offset a literal "
+                    "0 or a tile multiple of the grid index"))
     return diags
 
 

@@ -96,7 +96,7 @@ def segment_kernel_model(geom: _SegGeom, plan: BlockPlan,
         wiu = (geom.wo - 1) * geom.stride + geom.wf
         return dw_se_kernel_model(
             b=b, hiu=hiu, wiu=wiu, ho=geom.ho, wo=geom.wo, c=geom.c,
-            c_se=geom.g, hf=geom.hf, wf=geom.wf,
+            c_se=geom.g, hf=geom.hf, wf=geom.wf, stride=geom.stride,
             itemsize=nb, out_itemsize=nb, has_dw_bias=True,
         )
     if geom.kind in ("fused3", "fused2"):
@@ -114,7 +114,7 @@ def segment_kernel_model(geom: _SegGeom, plan: BlockPlan,
         return dw_kernel_model(
             b=b, hiu=hiu, wiu=wiu, ho=geom.ho, wo=geom.wo, c=geom.c,
             block_c=plan.block_c, hf=geom.hf, wf=geom.wf,
-            itemsize=nb, out_itemsize=nb,
+            stride=geom.stride, itemsize=nb, out_itemsize=nb,
         )
     assert geom.kind == "pw", geom.kind
     bg, bco, bci = pw_clamp_blocks(geom.g, geom.ci, geom.co,
@@ -340,7 +340,7 @@ def check_grid(model: KernelModel, *, segment: str = "",
 
     For every (sampled) grid point, every input ``index_map`` is evaluated:
     block-mode maps return block indices (in-bounds iff
-    ``(idx+1)*block <= array``), ``pl.unblocked`` maps return ELEMENT
+    ``(idx+1)*block <= array``), element-offset maps return ELEMENT
     offsets (in-bounds iff ``offset + block <= array``) — this is what
     proves the overlapping halo windows never read past the padded input.
     The output map must tile the output exactly: every output block

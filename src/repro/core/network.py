@@ -230,6 +230,30 @@ def init_network(key, net: NetworkSpec, dtype=jnp.float32) -> list:
     return params
 
 
+#: fp32 network vs :func:`reference_network`: both sides compute in fp32
+#: (the kernels contract fp32 streams at HIGHEST), so only summation order
+#: differs; one bf16 rounding of a GEMM operand would show as ~4e-3.
+FP32_REL_TOL = 1e-5
+
+#: bf16-streamed network vs the fp32 :func:`reference_network` (DESIGN.md
+#: §7): one bf16 rounding per streamed operand per block, compounded over
+#: 13-17 blocks, lands ~1e-2.
+BF16_REL_TOL = 5e-2
+
+
+def reference_network(net: NetworkSpec, params, x):
+    """The fp32 per-block oracle: every block through the XLA reference
+    lowering at ``"highest"`` matmul precision (XLA's TPU default is one
+    bf16 pass), with fault injection suppressed — the yardstick itself
+    must not degrade.  ``params`` are fp32 ``init_network`` params."""
+    from repro.runtime import faultinject  # lazy: runtime sits above core
+    oracle = KernelPolicy(impl="xla", on_failure="raise")
+    with faultinject.suppressed(), jax.default_matmul_precision("highest"):
+        for spec, p in zip(net.blocks, params):
+            x = chain.execute(spec, p, x, policy=oracle)
+    return x
+
+
 def cast_network_params(params, dtype) -> list:
     """Cast every parameter leaf once, up front — deployment-style weight
     storage at the stream width, making the lowering's per-call casts
@@ -259,6 +283,10 @@ class NetworkPlan:
     @property
     def n_kernel_passes(self) -> int:
         return sum(p.n_kernel_passes for p in self.plans)
+
+    @property
+    def n_pallas_calls(self) -> int:
+        return sum(p.n_pallas_calls for p in self.plans)
 
     @property
     def fully_fused(self) -> bool:

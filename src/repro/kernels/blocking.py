@@ -621,6 +621,13 @@ class ChainPlan:
         return n + (1 if self.residual and not self.residual_fused else 0)
 
     @property
+    def n_pallas_calls(self) -> int:
+        """The kernel passes that lower to a Pallas call: all but the
+        XLA-lowered ones (a standalone ``mb`` conv, a standalone residual
+        add) — what a compiled program counts as ``tpu_custom_call``s."""
+        return sum({"se": 2, "mb": 0}.get(s.kind, 1) for s in self.segments)
+
+    @property
     def fully_fused(self) -> bool:
         """The whole chain (incl. any residual) runs as ONE kernel pass."""
         return len(self.segments) == 1 and self.segments[0].kind in (

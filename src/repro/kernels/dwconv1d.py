@@ -71,15 +71,6 @@ def dwconv1d_causal_pallas(
     lp, dp = l + pad_l, d + pad_d
 
     kernel = functools.partial(_dw1d_kernel, k=k, out_dtype=x.dtype)
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-
     out = pl.pallas_call(
         kernel,
         grid=(b, dp // bd, lp // bl),
@@ -90,7 +81,8 @@ def dwconv1d_causal_pallas(
         out_specs=pl.BlockSpec((1, bl, bd), lambda i, j, s: (i, s, j)),
         out_shape=jax.ShapeDtypeStruct((b, lp, dp), x.dtype),
         scratch_shapes=[pltpu.VMEM((max(k - 1, 1), bd), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, f)
     return out[:, :l, :d]

@@ -18,7 +18,8 @@ DWConv has no matmul structure, so this is a pure-VPU kernel: an unrolled
 read once, filter once, output written once — AI = Hf·Wf/(1+1/…) FLOPs/byte,
 the paper's T^DW bound with the block terms at their VMEM-scale limits.
 
-Stride > 1 is handled with static strided slices on the H/W (non-minor) dims.
+Stride > 1 is handled with strided tap reads on the H/W (non-minor) dims,
+from an fp32 lane-chunked stage of the input block (``kernels/taps.py``).
 Padding is applied by the wrapper (ops.py) so the kernel sees VALID geometry.
 """
 from __future__ import annotations
@@ -28,17 +29,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import blocking
+from repro.kernels import blocking, taps
 from repro.kernels.gridspec import (BlockRef, KernelModel,
-                                    in_specs_from_model,
+                                    compiler_params, in_specs_from_model,
                                     out_spec_from_model)
 
 
 def dw_kernel_model(*, b: int, hiu: int, wiu: int, ho: int, wo: int, c: int,
-                    block_c: int, hf: int, wf: int, itemsize: int,
-                    out_itemsize: int) -> KernelModel:
+                    block_c: int, hf: int, wf: int, stride: int,
+                    itemsize: int, out_itemsize: int) -> KernelModel:
     """The exact grid/BlockSpec geometry ``dwconv2d_pallas`` lowers to —
     consumed by both the kernel and the static analyzer (DESIGN.md §8).
     ``hiu``/``wiu`` are the input rows/cols actually consumed; shapes are
@@ -57,27 +57,24 @@ def dw_kernel_model(*, b: int, hiu: int, wiu: int, ho: int, wo: int, c: int,
         ),
         output=BlockRef("out", (b, ho, wo, cp), (1, ho, wo, cb),
                         lambda i, j: (i, 0, 0, j), out_itemsize),
+        scratch_bytes=taps.stage_bytes((hiu, wiu, cb), stride > 1),
         value_bytes=ho * wo * cb * 4,              # fp32 jnp accumulator
     )
 
 
-def _dw2d_kernel(x_ref, f_ref, out_ref, *, hf: int, wf: int, stride: int,
-                 out_dtype):
-    """Blocks: x (1, Hi, Wi, Cb); f (Hf, Wf, Cb); out (1, Ho, Wo, Cb)."""
-    _, ho, wo, _ = out_ref.shape
-    x = x_ref[0].astype(jnp.float32)           # (Hi, Wi, Cb) — read once
+def _dw2d_kernel(x_ref, f_ref, out_ref, *stage, hf: int, wf: int,
+                 stride: int, out_dtype):
+    """Blocks: x (1, Hi, Wi, Cb); f (Hf, Wf, Cb); out (1, Ho, Wo, Cb);
+    ``stage``: x's fp32 lane-chunked copy when strided (``taps.py``)."""
+    _, ho, wo, cb = out_ref.shape
+    stage_ref = stage[0] if stage else None
+    if stage_ref is not None:
+        taps.stage_input(stage_ref, x_ref)
     f = f_ref[...].astype(jnp.float32)         # filter tile: VMEM-resident
     acc = jnp.zeros(out_ref.shape[1:], jnp.float32)
-    s = stride
     for n in range(hf):                        # unrolled taps (Hf·Wf ≤ 25)
         for m in range(wf):
-            # strided window of the input block for tap (n, m):
-            win = jax.lax.slice(
-                x,
-                (n, m, 0),
-                (n + (ho - 1) * s + 1, m + (wo - 1) * s + 1, x.shape[2]),
-                (s, s, 1),
-            )
+            win = taps.tap(x_ref, stage_ref, n, m, ho, wo, stride, cb)
             acc = acc + win * f[n, m][None, None, :]
     out_ref[0] = acc.astype(out_dtype)         # single store (lines 29-34)
 
@@ -131,7 +128,7 @@ def dwconv2d_pallas(
     # static analyzer (repro.analysis) checks (DESIGN.md §8).
     model = dw_kernel_model(
         b=b, hiu=hiu, wiu=wiu, ho=ho, wo=wo, c=c, block_c=cb, hf=hf, wf=wf,
-        itemsize=x.dtype.itemsize, out_itemsize=odt.itemsize,
+        stride=stride, itemsize=x.dtype.itemsize, out_itemsize=odt.itemsize,
     )
     for arr, br in zip((x, f), model.inputs):
         assert arr.shape == br.array_shape, (br.name, arr.shape,
@@ -140,22 +137,14 @@ def dwconv2d_pallas(
     kernel = functools.partial(
         _dw2d_kernel, hf=hf, wf=wf, stride=stride, out_dtype=odt
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-
     out = pl.pallas_call(
         kernel,
         grid=model.grid,
         in_specs=in_specs_from_model(model),
         out_specs=out_spec_from_model(model),
         out_shape=jax.ShapeDtypeStruct(model.output.array_shape, odt),
-        compiler_params=compiler_params,
+        scratch_shapes=taps.stage_shapes((hiu, wiu, cb), stride > 1),
+        compiler_params=compiler_params(model),
         interpret=interpret,
     )(x, f)
     return out[..., :c]

@@ -22,8 +22,9 @@ structure):
   accumulator ``(slab_h*Wo, Cob)`` stays VMEM-resident across the whole
   reduction and is stored exactly once.
 * the input window carries ALL ``Ci`` raw channels (it is every conv tap's
-  A-operand), fetched with ``pl.unblocked`` element-offset indexing per row
-  slab — adjacent slabs re-read the ``Hf - stride`` row halo.
+  A-operand), fetched with element-offset indexing per row slab —
+  adjacent slabs re-read the ``Hf - stride`` row halo.  Strided taps read
+  an fp32 lane-chunked stage of that window (``kernels/taps.py``).
 * per reduction step, the conv runs as ``Hf*Wf`` tap GEMMs:
   ``window(slab_h, Wo, Ci) . f[n, m] (Ci, Cb)`` accumulated in fp32 (MXU
   work — unlike the depthwise taps these contract over ``Ci``), then
@@ -49,11 +50,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import blocking
+from repro.kernels import blocking, taps
 from repro.kernels.epilogue import apply_epilogue as _epilogue
 from repro.kernels.gridspec import (BlockRef, KernelModel,
-                                    in_specs_from_model,
+                                    compiler_params, in_specs_from_model,
                                     out_spec_from_model)
+from repro.kernels.policy import contract_precision
 
 
 def fused_mb_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
@@ -106,7 +108,9 @@ def fused_mb_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
                              "arbitrary"),
         inputs=tuple(inputs),
         output=out_ref,
-        scratch_bytes=sh * wo * cob * 4,           # fp32 accumulator
+        scratch_bytes=(sh * wo * cob * 4           # fp32 accumulator
+                       + taps.stage_bytes((slab_hi, wiu, c_in),
+                                          stride > 1)),
         value_bytes=sh * wo * cb * 4,              # conv intermediate (fp32)
         reshapes=(((sh, wo, c_in), (sh * wo, c_in)),
                   ((sh, wo, cb), (sh * wo, cb))),
@@ -116,13 +120,14 @@ def fused_mb_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
 def _fused_mb_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
                      mb_activation, activation, has_mbb: bool,
                      has_pwb: bool, has_res: bool, out_dtype):
-    """refs = (x, mb_f, [mb_bias,] pw_w, [pw_bias,] [residual,] out, acc).
+    """refs = (x, mb_f, [mb_bias,] pw_w, [pw_bias,] [residual,] out, acc,
+    [stage]).
 
     Blocks: x (1, slab_hi, Wiu, Ci) — the overlapping raw-input window of
     this row slab, identical for every reduction step; mb_f
     (Hf, Wf, Ci, Cb); mb_bias (1, Cb); pw_w (Cb, Cob); pw_bias (1, Cob);
     residual / out (1, slab_h, Wo, Cob); acc VMEM scratch (slab_h*Wo, Cob)
-    fp32.
+    fp32; stage: x's fp32 lane-chunked copy when strided (``taps.py``).
     """
     it = iter(refs)
     x_ref = next(it)
@@ -133,6 +138,7 @@ def _fused_mb_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     res_ref = next(it) if has_res else None
     out_ref = next(it)
     acc_ref = next(it)
+    stage_ref = next(it, None)
 
     _, slab_h, wo, cob = out_ref.shape
     cb = f_ref.shape[3]
@@ -142,24 +148,20 @@ def _fused_mb_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)
-    ci = x.shape[2]
+    if stage_ref is not None:
+        taps.stage_input(stage_ref, x_ref)
+    ci = x_ref.shape[3]
     f = f_ref[...].astype(jnp.float32)
-    s = stride
+    prec = contract_precision(x_ref.dtype)
 
     # --- conv stage: Hf*Wf tap GEMMs contracting over the raw channels ---
     conv = jnp.zeros((slab_h * wo, cb), jnp.float32)
     for n in range(hf):
         for m in range(wf):
-            win = jax.lax.slice(
-                x,
-                (n, m, 0),
-                (n + (slab_h - 1) * s + 1, m + (wo - 1) * s + 1, ci),
-                (s, s, 1),
-            )
+            win = taps.tap(x_ref, stage_ref, n, m, slab_h, wo, stride, ci)
             conv = conv + jnp.dot(
                 win.reshape(slab_h * wo, ci), f[n, m],
-                preferred_element_type=jnp.float32,
+                preferred_element_type=jnp.float32, precision=prec,
             )
     conv = _epilogue(
         conv, mbb_ref[0][None, :] if mbb_ref is not None else None,
@@ -169,7 +171,7 @@ def _fused_mb_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     # --- projection: conv tile (VMEM value, never stored) is the A-operand
     acc_ref[...] += jnp.dot(
         conv, w_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=prec,
     )
 
     @pl.when(k == nk - 1)
@@ -301,15 +303,6 @@ def fused_mbconv_pallas(
         has_mbb=mb_bias is not None, has_pwb=pw_bias is not None,
         has_res=residual is not None, out_dtype=odt,
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-
     assert model.output.array_shape == (b, ho_p, wo, cop)
     out = pl.pallas_call(
         kernel,
@@ -317,8 +310,9 @@ def fused_mbconv_pallas(
         in_specs=in_specs_from_model(model),
         out_specs=out_spec_from_model(model),
         out_shape=jax.ShapeDtypeStruct(model.output.array_shape, odt),
-        scratch_shapes=[pltpu.VMEM((sh * wo, cob), jnp.float32)],
-        compiler_params=compiler_params,
+        scratch_shapes=[pltpu.VMEM((sh * wo, cob), jnp.float32)]
+        + taps.stage_shapes(((sh - 1) * stride + hf, wiu, c_in), stride > 1),
+        compiler_params=compiler_params(model),
         interpret=interpret,
     )(*inputs)
     return out[:, :ho, :, :co]

@@ -20,13 +20,22 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Optional, Tuple
+from typing import Callable, Tuple
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-#: Physical VMEM per TensorCore the derived working set must never exceed
-#: (the planner budgets 12 MiB of this to leave Mosaic headroom).
+#: Ceiling the derived working set must never exceed (the planner budgets
+#: 12 MiB of this); it is Mosaic's default scoped-VMEM limit.
 VMEM_HARD_BYTES = 16 * 1024 * 1024
+
+#: Scoped-VMEM limit every kernel compiles under.  The derived working set
+#: counts operands, scratch and the named fp32 values, but Mosaic also
+#: spills the in-kernel temporaries (per-tap windows, the pre-stage
+#: expanded value) to VMEM; at 112x112 those pushed V2's first stride-2
+#: inverted residual past the 16 MiB default on v5e.  A v5e TensorCore has
+#: 128 MiB of VMEM.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,11 +43,11 @@ class BlockRef:
     """One operand's block geometry: the (padded) array the kernel is passed,
     the VMEM block shape, and the grid -> block index map.
 
-    ``unblocked`` marks element-offset (``pl.unblocked``) indexing — the
-    index map then returns ELEMENT offsets, not block indices (the fused
-    kernel's overlapping halo windows).  ``streamed`` operands are pipelined
-    HBM<->VMEM by Mosaic and therefore double-buffered in the VMEM
-    accounting.
+    ``unblocked`` marks element-offset indexing (every block dim a
+    ``pl.Element``) — the index map then returns ELEMENT offsets, not block
+    indices (the fused kernels' overlapping halo windows).  ``streamed``
+    operands are pipelined HBM<->VMEM by Mosaic and therefore
+    double-buffered in the VMEM accounting.
     """
     name: str
     array_shape: Tuple[int, ...]
@@ -67,9 +76,9 @@ class KernelModel:
     ``pl.pallas_call`` will be handed, in checkable form.
 
     ``scratch_bytes`` covers explicit VMEM scratch allocations (fp32
-    accumulators); ``value_bytes`` the persistent in-kernel fp32 values the
-    planner budgets (DW intermediate, expanded slab) that are neither
-    operands nor scratch.  ``reshapes`` records in-kernel reshape shapes for
+    accumulators, the tap stage of ``kernels/taps.py``); ``value_bytes``
+    the persistent in-kernel fp32 values the planner budgets (the DW or
+    conv intermediate) that are neither operands nor scratch.  ``reshapes`` records in-kernel reshape shapes for
     the Mosaic sublane-collapse lint (``analysis/mosaic_check.py``).
     """
     name: str
@@ -99,8 +108,10 @@ def in_specs_from_model(model: KernelModel) -> list:
     specs = []
     for br in model.inputs:
         if br.unblocked:
-            specs.append(pl.BlockSpec(br.block_shape, br.index_map,
-                                      indexing_mode=pl.unblocked))
+            # JAX refuses a mix: every dim of an element-offset window is
+            # an Element, the batch dim included
+            shape = tuple(pl.Element(d) for d in br.block_shape)
+            specs.append(pl.BlockSpec(shape, br.index_map))
         else:
             specs.append(pl.BlockSpec(br.block_shape, br.index_map))
     return specs
@@ -108,3 +119,10 @@ def in_specs_from_model(model: KernelModel) -> list:
 
 def out_spec_from_model(model: KernelModel) -> pl.BlockSpec:
     return pl.BlockSpec(model.output.block_shape, model.output.index_map)
+
+
+def compiler_params(model: KernelModel) -> pltpu.CompilerParams:
+    """The Mosaic parameters the kernel passes as ``compiler_params``."""
+    return pltpu.CompilerParams(
+        dimension_semantics=model.dimension_semantics,
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)

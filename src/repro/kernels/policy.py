@@ -86,6 +86,18 @@ NATIVE = DtypePolicy()
 BF16_STREAM = DtypePolicy(stream="bfloat16")
 
 
+def contract_precision(stream_dtype):
+    """MXU precision of the in-kernel GEMMs for operands streamed at
+    ``stream_dtype``.  An fp32 stream asks for fp32 contraction
+    (``HIGHEST``) rather than rely on Mosaic's default, so an fp32 network
+    is fp32 end to end; a narrower stream contracts in one bf16 pass, as
+    its weights already are bf16."""
+    import jax.numpy as jnp
+    if jnp.dtype(stream_dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
 def resolve_impl(impl: str) -> str:
     """'auto' -> 'pallas' on TPU backends, 'xla' elsewhere; else pass-through.
 

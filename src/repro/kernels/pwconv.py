@@ -29,8 +29,9 @@ from jax.experimental.pallas import tpu as pltpu
 # inside the kernel body; `_epilogue` stays as an alias for old call sites.
 from repro.kernels.epilogue import apply_epilogue as _epilogue
 from repro.kernels.gridspec import (BlockRef, KernelModel,
-                                    in_specs_from_model,
+                                    compiler_params, in_specs_from_model,
                                     out_spec_from_model)
+from repro.kernels.policy import contract_precision
 
 
 def pw_clamp_blocks(g: int, ci: int, co: int, block_g: int, block_co: int,
@@ -91,7 +92,8 @@ def _rtrd_kernel(*refs, nk: int, activation, out_dtype):
 
     # The output tile (acc) stays resident; only A/B tiles stream. == RTRD.
     acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        x_ref[...], w_ref[...], preferred_element_type=jnp.float32,
+        precision=contract_precision(x_ref.dtype),
     )
 
     @pl.when(k == nk - 1)
@@ -170,15 +172,6 @@ def pwconv_pallas(
     kernel = functools.partial(
         _rtrd_kernel, nk=nk, activation=activation, out_dtype=out_dtype
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-    except AttributeError:  # older naming
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-
     out = pl.pallas_call(
         kernel,
         grid=model.grid,
@@ -186,7 +179,7 @@ def pwconv_pallas(
         out_specs=out_spec_from_model(model),
         out_shape=jax.ShapeDtypeStruct(model.output.array_shape, out_dtype),
         scratch_shapes=[pltpu.VMEM((bg, bco), jnp.float32)],
-        compiler_params=compiler_params,
+        compiler_params=compiler_params(model),
         interpret=interpret,
     )(*inputs)
     return out[:g, :co]

@@ -34,17 +34,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import blocking
+from repro.kernels import blocking, taps
 from repro.kernels.epilogue import apply_epilogue as _epilogue
 from repro.kernels.gridspec import (BlockRef, KernelModel,
-                                    in_specs_from_model,
+                                    compiler_params, in_specs_from_model,
                                     out_spec_from_model)
+from repro.kernels.policy import contract_precision
 
 
 def dw_se_kernel_model(*, b: int, hiu: int, wiu: int, ho: int, wo: int,
-                       c: int, c_se: int, hf: int, wf: int,
+                       c: int, c_se: int, hf: int, wf: int, stride: int,
                        itemsize: int, out_itemsize: int,
                        has_dw_bias: bool) -> KernelModel:
     """The exact grid/BlockSpec geometry ``dw_se_pallas`` lowers to —
@@ -75,7 +75,7 @@ def dw_se_kernel_model(*, b: int, hiu: int, wiu: int, ho: int, wo: int,
         dimension_semantics=("parallel",),
         inputs=tuple(inputs),
         output=out_ref,
-        scratch_bytes=0,
+        scratch_bytes=taps.stage_bytes((hiu, wiu, c), stride > 1),
         value_bytes=ho * wo * c * 4,          # DW intermediate (fp32)
         reshapes=(((ho, wo, c), (ho * wo, c)),),
     )
@@ -83,11 +83,12 @@ def dw_se_kernel_model(*, b: int, hiu: int, wiu: int, ho: int, wo: int,
 
 def _dw_se_kernel(*refs, hf: int, wf: int, stride: int,
                   dw_activation, se_activation, has_dwb: bool, out_dtype):
-    """refs = (x, dw_f, [dw_bias,] w1, b1, w2, b2, out).
+    """refs = (x, dw_f, [dw_bias,] w1, b1, w2, b2, out, [stage]).
 
     Blocks: x (1, Hiu, Wiu, C) — one image's whole (VALID) input window;
     dw_f (Hf, Wf, C); dw_bias (1, C); w1 (C, Cse); b1 (1, Cse);
-    w2 (Cse, C); b2 (1, C); out (1, Ho, Wo, C).
+    w2 (Cse, C); b2 (1, C); out (1, Ho, Wo, C); stage: x's fp32
+    lane-chunked copy when strided (``taps.py``).
     """
     it = iter(refs)
     x_ref = next(it)
@@ -98,22 +99,18 @@ def _dw_se_kernel(*refs, hf: int, wf: int, stride: int,
     w2_ref = next(it)
     b2_ref = next(it)
     out_ref = next(it)
+    stage_ref = next(it, None)
+    if stage_ref is not None:
+        taps.stage_input(stage_ref, x_ref)
 
     _, ho, wo, c = out_ref.shape
-    x = x_ref[0].astype(jnp.float32)
     f = f_ref[...].astype(jnp.float32)
-    s = stride
 
     # --- DW stage: shift-and-FMA over ALL channels (dwconv2d Alg. 4) ---
     dw = jnp.zeros((ho, wo, c), jnp.float32)
     for n in range(hf):
         for m in range(wf):
-            win = jax.lax.slice(
-                x,
-                (n, m, 0),
-                (n + (ho - 1) * s + 1, m + (wo - 1) * s + 1, c),
-                (s, s, 1),
-            )
+            win = taps.tap(x_ref, stage_ref, n, m, ho, wo, stride, c)
             dw = dw + win * f[n, m][None, None, :]
     dw = _epilogue(
         dw, dwb_ref[0][None, None, :] if dwb_ref is not None else None,
@@ -124,12 +121,13 @@ def _dw_se_kernel(*refs, hf: int, wf: int, stride: int,
     # (every intermediate is a VMEM value; the DW output is never stored
     # unscaled)
     pooled = jnp.mean(dw.reshape(ho * wo, c), axis=0, keepdims=True)
+    prec = contract_precision(x_ref.dtype)
     hid = jnp.dot(pooled, w1_ref[...].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32, precision=prec)
     hid = _epilogue(hid, b1_ref[0][None, :].astype(jnp.float32),
                     se_activation)
     gate = jnp.dot(hid, w2_ref[...].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32, precision=prec)
     gate = jax.nn.sigmoid(gate + b2_ref[0][None, :].astype(jnp.float32))
 
     out_ref[0] = (dw * gate.reshape(1, 1, c)).astype(out_dtype)
@@ -186,7 +184,7 @@ def dw_se_pallas(
     x = x[:, :hiu, :wiu, :]
     model = dw_se_kernel_model(
         b=b, hiu=hiu, wiu=wiu, ho=ho, wo=wo, c=c, c_se=c_se, hf=hf, wf=wf,
-        itemsize=x.dtype.itemsize, out_itemsize=odt.itemsize,
+        stride=stride, itemsize=x.dtype.itemsize, out_itemsize=odt.itemsize,
         has_dw_bias=dw_bias is not None,
     )
     inputs = [x, dw_f]
@@ -202,21 +200,13 @@ def dw_se_pallas(
         dw_activation=dw_activation, se_activation=se_activation,
         has_dwb=dw_bias is not None, out_dtype=odt,
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-
     return pl.pallas_call(
         kernel,
         grid=model.grid,
         in_specs=in_specs_from_model(model),
         out_specs=out_spec_from_model(model),
         out_shape=jax.ShapeDtypeStruct(model.output.array_shape, odt),
-        compiler_params=compiler_params,
+        scratch_shapes=taps.stage_shapes((hiu, wiu, c), stride > 1),
+        compiler_params=compiler_params(model),
         interpret=interpret,
     )(*inputs)

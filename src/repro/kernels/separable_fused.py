@@ -23,8 +23,8 @@ slab dimension):
   ``(slab_h*Wo, Cob)`` stays VMEM-resident across the whole reduction of
   its slab and is stored exactly once.
 * the **row-slab dimension** bounds the accumulator: each grid cell owns
-  ``slab_h`` output rows, and the input BlockSpec (``pl.unblocked``
-  element-offset indexing) fetches the overlapping
+  ``slab_h`` output rows, and the input BlockSpec (element-offset
+  indexing) fetches the overlapping
   ``(slab_h-1)*stride + Hf`` input-row window for that slab — adjacent
   slabs re-fetch a ``Hf - stride`` row halo at each interior seam. This is
   what lifts the old ~1.5M-pixel accumulator ceiling (DESIGN.md §3): any
@@ -45,10 +45,10 @@ contribution is exactly zero. Row padding (when ``slab_h`` does not divide
 
 Expand-on-the-fly (the 3-stage V2 chain, DESIGN.md §5): with ``expand_w``
 ``(Ci, C)`` given, the kernel's input is the RAW ``Ci``-channel tensor and
-each reduction step first computes its expanded-channel slab as a per-slab
-GEMM — ``x_window.reshape(slab_hi*Wiu, Ci) @ expand_w[:, k*Cb:(k+1)*Cb]``
-into a VMEM fp32 value — applies the expand activation, and feeds that
-value to the DW shift-and-FMA in place of the streamed input.  Neither the
+each reduction step first computes its expanded-channel slab, one GEMM per
+input row — ``x_window[h] (Wiu, Ci) @ expand_w[:, k*Cb:(k+1)*Cb]`` — into
+VMEM fp32 scratch, applies the expand activation, and feeds that
+slab to the DW shift-and-FMA in place of the streamed input.  Neither the
 expanded tensor (``B*Hi*Wi*C`` — 6x the input at the usual expansion
 factor) nor the DW output ever exists in HBM.  Restriction: the expansion
 must be bias-free, because SAME padding is applied to the raw input before
@@ -63,9 +63,11 @@ enumeration); when even the minimal plan exceeds the budget the planner
 returns None and callers fall back to the unfused composition
 (``ops.separable_fused``).
 
-TPU note: the overlapping input windows use ``pl.unblocked`` indexing,
-validated in interpret mode like the rest of this package; Mosaic sublane
-alignment of un-tiled row offsets is part of the ROADMAP hardware item.
+TPU note: the overlapping input windows use element-offset indexing
+(``pl.Element`` on every block dim, ``gridspec.in_specs_from_model``); the
+row offset is un-tiled and free, the lane offset must be provably
+128-aligned (MC204).  The DW taps of a strided or expanded block
+read an fp32 lane-chunked VMEM stage (``kernels/taps.py``).
 """
 from __future__ import annotations
 
@@ -77,11 +79,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import blocking
+from repro.kernels import blocking, taps
 from repro.kernels.epilogue import apply_epilogue as _epilogue
 from repro.kernels.gridspec import (BlockRef, KernelModel,
-                                    in_specs_from_model,
+                                    compiler_params, in_specs_from_model,
                                     out_spec_from_model)
+from repro.kernels.policy import contract_precision
 
 
 def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
@@ -114,7 +117,9 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
 
     # x window: element-offset (unblocked) indexing — adjacent slabs'
     # windows overlap by the (hf - stride)-row halo.  With expand the
-    # window carries ALL raw channels; without, one channel slab.
+    # window carries ALL raw channels; without, one channel slab.  A single
+    # channel slab gets a literal 0 lane offset: Mosaic must prove the
+    # offset 128-aligned, and cannot for ``k * cb`` with cb < 128 (MC204).
     if has_expand:
         x_ref = BlockRef(
             "x", (b, rows_in, wiu, c_in), (1, slab_hi, wiu, c_in),
@@ -123,8 +128,8 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
     else:
         x_ref = BlockRef(
             "x", (b, rows_in, wiu, cp), (1, slab_hi, wiu, cb),
-            lambda i, s, j, k, sh=sh, st=stride, cb=cb:
-                (i, s * sh * st, 0, k * cb),
+            lambda i, s, j, k, sh=sh, st=stride, cb=cb, nk=nk:
+                (i, s * sh * st, 0, k * cb if nk > 1 else 0),
             itemsize, unblocked=True)
     inputs = [x_ref]
     if has_expand:
@@ -147,10 +152,6 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
     out_ref = BlockRef("out", (b, ho_p, wo, cop), (1, sh, wo, cob),
                        lambda i, s, j, k: (i, s, 0, j), out_itemsize)
     reshapes = [((sh, wo, cb), (sh * wo, cb))]
-    value_bytes = sh * wo * cb * 4                 # DW intermediate (fp32)
-    if has_expand:
-        reshapes.insert(0, ((slab_hi, wiu, c_in), (slab_hi * wiu, c_in)))
-        value_bytes += slab_hi * wiu * cb * 4      # expanded slab (fp32)
     return KernelModel(
         name="separable_fused3" if has_expand else "separable_fused2",
         grid=(b, n_slabs, cop // cob, nk),
@@ -158,8 +159,10 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
                              "arbitrary"),
         inputs=tuple(inputs),
         output=out_ref,
-        scratch_bytes=sh * wo * cob * 4,           # fp32 accumulator
-        value_bytes=value_bytes,
+        scratch_bytes=(sh * wo * cob * 4           # fp32 accumulator
+                       + taps.stage_bytes((slab_hi, wiu, cb),
+                                          has_expand or stride > 1)),
+        value_bytes=sh * wo * cb * 4,              # DW intermediate (fp32)
         reshapes=tuple(reshapes),
     )
 
@@ -169,13 +172,15 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
                   expand_activation, has_dwb: bool, has_pwb: bool,
                   has_res: bool, out_dtype):
     """refs = (x, [expand_w,] f, [dw_bias,] w, [pw_bias,] [residual,] out,
-    acc).
+    acc, [stage]).
 
     Blocks: x (1, slab_hi, Wiu, Cb) — the overlapping input window of this
     row slab (with expand: (1, slab_hi, Wiu, Ci), the RAW input, identical
     for every reduction step); expand_w (Ci, Cb); f (Hf, Wf, Cb); dw_bias
     (1, Cb); w (Cb, Cob); pw_bias (1, Cob); residual (1, slab_h, Wo, Cob);
-    out (1, slab_h, Wo, Cob); acc VMEM scratch (slab_h*Wo, Cob) fp32.
+    out (1, slab_h, Wo, Cob); acc VMEM scratch (slab_h*Wo, Cob) fp32;
+    stage: the fp32 lane-chunked DW input (the expanded slab, or x when
+    strided — ``taps.py``).
     """
     it = iter(refs)
     x_ref = next(it)
@@ -187,6 +192,7 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     res_ref = next(it) if has_res else None
     out_ref = next(it)
     acc_ref = next(it)
+    stage_ref = next(it, None)
 
     _, slab_h, wo, cob = out_ref.shape
     cb = f_ref.shape[2]
@@ -196,30 +202,31 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.float32)
     if ew_ref is not None:
         # --- expand stage: this step's expanded-channel slab, on the fly ---
-        # (slab_hi*Wiu, Ci) @ (Ci, Cb) -> fp32 VMEM value; never in HBM.
-        slab_hi, wiu, ci = x.shape
-        ex = jnp.dot(
-            x.reshape(slab_hi * wiu, ci),
-            ew_ref[...].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )
-        x = _epilogue(ex, None, expand_activation).reshape(slab_hi, wiu, cb)
+        # one (Wiu, Ci) @ (Ci, Cb) GEMM per input row into the fp32 stage;
+        # never in HBM.  Row by row, because collapsing (slab_hi, Wiu) into
+        # GEMM rows is a relayout when Wiu is off the sublane tile.
+        ew = ew_ref[...].astype(jnp.float32)
+        prec = contract_precision(x_ref.dtype)
+
+        def expand_row(h, carry):
+            ex = jnp.dot(x_ref[0, h].astype(jnp.float32), ew,
+                         preferred_element_type=jnp.float32, precision=prec)
+            taps.stage(stage_ref, _epilogue(ex, None, expand_activation),
+                       row=h)
+            return carry
+
+        jax.lax.fori_loop(0, x_ref.shape[1], expand_row, 0)
+    elif stage_ref is not None:
+        taps.stage_input(stage_ref, x_ref)
 
     # --- DW stage: shift-and-FMA over the channel slab (dwconv2d Alg. 4) ---
     f = f_ref[...].astype(jnp.float32)
-    s = stride
     dw = jnp.zeros((slab_h, wo, cb), jnp.float32)
     for n in range(hf):
         for m in range(wf):
-            win = jax.lax.slice(
-                x,
-                (n, m, 0),
-                (n + (slab_h - 1) * s + 1, m + (wo - 1) * s + 1, cb),
-                (s, s, 1),
-            )
+            win = taps.tap(x_ref, stage_ref, n, m, slab_h, wo, stride, cb)
             dw = dw + win * f[n, m][None, None, :]
     dw = _epilogue(
         dw, dwb_ref[0][None, None, :] if dwb_ref is not None else None,
@@ -229,7 +236,8 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     # --- PW stage: DW tile (VMEM value, never stored) is the A-operand ---
     a = dw.reshape(slab_h * wo, cb)
     acc_ref[...] += jnp.dot(
-        a, w_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
+        a, w_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32,
+        precision=contract_precision(x_ref.dtype),
     )
 
     @pl.when(k == nk - 1)
@@ -367,7 +375,7 @@ def separable_fused_pallas(
     # object the static analyzer (repro.analysis) checks, so what is proven
     # statically is what executes (DESIGN.md §8).  Input windows of adjacent
     # slabs overlap by (hf - stride) halo rows, so the x BlockSpec uses
-    # element-offset (unblocked) indexing; with expand the window carries
+    # element-offset indexing; with expand the window carries
     # ALL raw channels (Ci is small; the reduction steps slab the EXPANDED
     # channels via the expand_w block instead).
     model = fused_kernel_model(
@@ -400,14 +408,8 @@ def separable_fused_pallas(
         has_dwb=dw_bias is not None, has_pwb=pw_bias is not None,
         has_res=residual is not None, out_dtype=odt,
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
-    except AttributeError:
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=model.dimension_semantics
-        )
+    stage = taps.stage_shapes((slab_hi, wiu, cb),
+                              expand_w is not None or stride > 1)
 
     assert model.output.array_shape == (b, ho_p, wo, cop)
     out = pl.pallas_call(
@@ -416,8 +418,8 @@ def separable_fused_pallas(
         in_specs=in_specs,
         out_specs=out_spec_from_model(model),
         out_shape=jax.ShapeDtypeStruct(model.output.array_shape, odt),
-        scratch_shapes=[pltpu.VMEM((sh * wo, cob), jnp.float32)],
-        compiler_params=compiler_params,
+        scratch_shapes=[pltpu.VMEM((sh * wo, cob), jnp.float32)] + stage,
+        compiler_params=compiler_params(model),
         interpret=interpret,
     )(*inputs)
     return out[:, :ho, :, :co]

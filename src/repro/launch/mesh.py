@@ -11,20 +11,9 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh with Auto axis_types where the API exists.
-
-    Older jax (< 0.5) has neither ``jax.sharding.AxisType`` (AttributeError)
-    nor the ``axis_types`` kwarg (TypeError); Auto was its only behavior, so
-    plain make_mesh is equivalent there. Tests use this too — the tier-1
-    suite must run on the pinned 0.4.x toolchain.
-    """
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (TypeError, AttributeError):
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis of type Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 _make = make_mesh_compat

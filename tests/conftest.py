@@ -19,83 +19,3 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
 
-
-# ---------------------------------------------------------------------------
-# Optional-hypothesis shim: the tier-1 suite must collect and run without the
-# `hypothesis` package. When it is absent we install a minimal stand-in that
-# replays a small FIXED, deterministic example set per property test (seeded
-# RNG, capped example count) instead of true property-based search. With real
-# hypothesis installed this block is a no-op and full search applies.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import random as _random
-    import types as _types
-
-    _MAX_EXAMPLES = 5  # fixed-set fallback: keep deterministic and fast
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def example(self, rng):
-            return self._draw(rng)
-
-    def _integers(min_value, max_value):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _sampled_from(elements):
-        seq = list(elements)
-        return _Strategy(lambda rng: rng.choice(seq))
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.choice([False, True]))
-
-    def _floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def _given(*_args, **strategies):
-        if _args:
-            raise TypeError(
-                "hypothesis shim supports keyword strategies only")
-
-        def decorate(fn):
-            # *args/**kw signature on purpose: pytest must not see the
-            # strategy names as fixture parameters (no functools.wraps —
-            # __wrapped__ would expose the original signature).
-            def wrapper(*args, **kw):
-                n = getattr(wrapper, "_max_examples", _MAX_EXAMPLES)
-                rng = _random.Random(0xC0FFEE)
-                for _ in range(n):
-                    ex = {k: s.example(rng) for k, s in strategies.items()}
-                    fn(*args, **kw, **ex)
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            return wrapper
-
-        return decorate
-
-    def _settings(max_examples=_MAX_EXAMPLES, deadline=None, **_kw):
-        def decorate(fn):
-            fn._max_examples = min(max_examples, _MAX_EXAMPLES)
-            return fn
-
-        return decorate
-
-    _st = _types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _st.sampled_from = _sampled_from
-    _st.booleans = _booleans
-    _st.floats = _floats
-
-    _hyp = _types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__is_repro_shim__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

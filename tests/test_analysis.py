@@ -135,8 +135,8 @@ def test_pl113_misaligned_gemm_split():
 def _dw_model(c=32, block_c=32, ho=8):
     from repro.kernels.dwconv2d import dw_kernel_model
     return dw_kernel_model(b=1, hiu=ho + 2, wiu=ho + 2, ho=ho, wo=ho, c=c,
-                           block_c=block_c, hf=3, wf=3, itemsize=4,
-                           out_itemsize=4)
+                           block_c=block_c, hf=3, wf=3, stride=1,
+                           itemsize=4, out_itemsize=4)
 
 
 def test_pl103_derived_vmem():
@@ -284,11 +284,21 @@ def _unblocked_model(index_map):
 def test_mc204_unblocked_offsets():
     aligned = mosaic_check.check_unblocked(
         _unblocked_model(lambda i: (i * 8, 0)))
-    assert [d.severity for d in aligned] == [INFO]  # surfaced, not flagged
+    assert aligned == []
     skewed = mosaic_check.check_unblocked(
         _unblocked_model(lambda i: (i * 8 + 1, 0)))
-    assert [d.severity for d in skewed] == [INFO, WARNING]
-    assert all(d.rule == "MC204" for d in skewed)
+    assert [(d.rule, d.severity) for d in skewed] == [("MC204", ERROR)]
+    # the v5e compiler refuses a lane offset it cannot prove 128-aligned,
+    # even one that is 0 at every grid point: k * 32 with one channel slab
+    lanes = BlockRef("x", (8, 32), (8, 32), lambda i: (0, i * 32), 4,
+                     unblocked=True)
+    out = BlockRef("o", (8, 32), (8, 32), lambda i: (0, 0), 4)
+    unproven = KernelModel("toy", (1,), ("parallel",), (lanes,), out)
+    assert _rules(mosaic_check.check_unblocked(unproven)) == ["MC204"]
+    literal = dataclasses.replace(
+        unproven, inputs=(dataclasses.replace(
+            lanes, index_map=lambda i: (0, 0)),))
+    assert mosaic_check.check_unblocked(literal) == []
 
 
 def test_mc205_reduction_dim_not_innermost():
