@@ -36,6 +36,7 @@ import collections
 import dataclasses
 import hashlib
 import json
+import time
 import warnings
 from typing import Optional, Sequence, Tuple
 
@@ -46,6 +47,7 @@ from repro.core import chain
 from repro.kernels import autotune, lowering
 from repro.kernels.blocking import ChainPlan
 from repro.kernels.policy import DEFAULT_POLICY, DtypePolicy, KernelPolicy
+from repro.runtime import telemetry
 
 
 # ---------------------------------------------------------------------------
@@ -585,8 +587,10 @@ def build_network_fn(net: NetworkSpec, nplan: NetworkPlan,
 
     def run(params, x):
         assert len(params) == len(runners), (len(params), len(runners))
-        for r, p in zip(runners, params):
-            x = r(p, x)
+        for i, (r, p) in enumerate(zip(runners, params)):
+            # compile-time only: names the block in every op's metadata
+            with jax.named_scope(f"b{i:02d}"):
+                x = r(p, x)
         return x
 
     return run
@@ -640,24 +644,34 @@ def _execute_network_raw(net: NetworkSpec, params, x, *,
     """The unguarded engine behind :func:`execute_network`: plan, jit,
     memoize, run.  The (plan, runner) pair is memoized only AFTER its
     first call succeeds — a plan whose trace/compile fails must not poison
-    the memo, or the re-plan after a quarantine write could never happen."""
-    cache_key = (net, x.shape, jnp.dtype(x.dtype).name, policy,
-                 block_dtype_policies, network_plan)
-    hit = _NETWORK_CACHE.get(cache_key)
+    the memo, or the re-plan after a quarantine write could never happen.
+
+    Host spans (``telemetry.span``, DESIGN.md §9): ``network.memo`` (key +
+    lookup), ``network.call`` (a hit's jitted call) and ``network.build``
+    (a miss); a successful miss also counts as one ``network.builds``.
+    With tracing off each span is a flag test and a shared null context."""
+    with telemetry.span("network.memo"):
+        cache_key = (net, x.shape, jnp.dtype(x.dtype).name, policy,
+                     block_dtype_policies, network_plan)
+        hit = _NETWORK_CACHE.get(cache_key)
     if hit is not None:
-        return hit[1](params, x)
-    nplan = network_plan
-    if nplan is None:
-        if policy.autotune:
-            nplan = tune_network(
-                net, params, x, policy=policy,
-                block_dtype_policies=block_dtype_policies).plan
-        else:
-            nplan = plan_network(
-                net, x.shape, dtype=x.dtype, policy=policy,
-                block_dtype_policies=block_dtype_policies)
-    fn = jax.jit(build_network_fn(net, nplan, policy,
-                                  block_dtype_policies))
-    y = fn(params, x)
+        with telemetry.span("network.call"):
+            return hit[1](params, x)
+    t0 = time.perf_counter_ns()
+    with telemetry.span("network.build"):
+        nplan = network_plan
+        if nplan is None:
+            if policy.autotune:
+                nplan = tune_network(
+                    net, params, x, policy=policy,
+                    block_dtype_policies=block_dtype_policies).plan
+            else:
+                nplan = plan_network(
+                    net, x.shape, dtype=x.dtype, policy=policy,
+                    block_dtype_policies=block_dtype_policies)
+        fn = jax.jit(build_network_fn(net, nplan, policy,
+                                      block_dtype_policies))
+        y = fn(params, x)
     _NETWORK_CACHE[cache_key] = (nplan, fn)
+    telemetry.record_build(time.perf_counter_ns() - t0)
     return y

@@ -84,5 +84,6 @@ def dwconv1d_causal_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dwconv1d",
     )(x, f)
     return out[:, :l, :d]

@@ -146,5 +146,6 @@ def dwconv2d_pallas(
         scratch_shapes=taps.stage_shapes((hiu, wiu, cb), stride > 1),
         compiler_params=compiler_params(model),
         interpret=interpret,
+        name="dwconv2d",
     )(x, f)
     return out[..., :c]

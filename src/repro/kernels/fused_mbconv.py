@@ -314,5 +314,6 @@ def fused_mbconv_pallas(
         + taps.stage_shapes(((sh - 1) * stride + hf, wiu, c_in), stride > 1),
         compiler_params=compiler_params(model),
         interpret=interpret,
+        name="fusedmb",
     )(*inputs)
     return out[:, :ho, :, :co]

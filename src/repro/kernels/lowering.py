@@ -224,64 +224,66 @@ def lower(spec, chain_plan: ChainPlan,
             seg_res = res if (chain_plan.residual_fused and last) else None
             try:
                 faultinject.check(_INJECT[seg.kind])
-                if seg.kind in ("fused3", "fused2"):
-                    y = _run_fused(seg, stages, params, y, seg_res,
-                                   impl=impl, interpret=interpret,
-                                   stream_dtype=sdt, out_dtype=k_out)
-                elif seg.kind == "fusedmb":
-                    y = _run_fused_mb(seg, stages, params, y, seg_res,
-                                      impl=impl, interpret=interpret,
-                                      stream_dtype=sdt, out_dtype=k_out)
-                elif seg.kind == "dw_se":
-                    y = _run_dw_se(seg, stages, params, y,
-                                   impl=impl, interpret=interpret,
-                                   stream_dtype=sdt, out_dtype=k_out)
-                elif seg.kind == "se":
-                    y = _run_se(seg, stages, params, y, policy,
-                                impl=impl, interpret=interpret,
-                                stream_dtype=sdt, out_dtype=k_out)
-                elif seg.kind == "mb":
-                    # standalone dense conv: XLA-lowered on every impl —
-                    # the dense conv is MXU-shaped as-is, the Pallas win is
-                    # the fused projection (segment kind "fusedmb")
-                    st = stages[seg.stages[0]]
-                    p = params[seg.stages[0]]
-                    y = ref.conv2d_ref(
-                        y, p["f"].astype(sdt), _cast(p.get("b"), sdt),
-                        stride=st.stride, padding=st.padding,
-                        activation=st.activation,
-                    ).astype(k_out)
-                elif seg.kind == "pw":
-                    st = stages[seg.stages[0]]
-                    p = params[seg.stages[0]]
-                    y = ops.pwconv(
-                        y, p["w"].astype(sdt), _cast(p.get("b"), sdt),
-                        activation=st.activation,
-                        impl=impl, interpret=interpret,
-                        block_g=policy.block_g or seg.plan.block_g,
-                        block_co=policy.block_co or seg.plan.block_co,
-                        block_ci=policy.block_ci or seg.plan.block_c,
-                        vmem_budget=policy.vmem_budget,
-                        out_dtype=jnp.dtype(k_out).name,
-                    )
-                else:  # "dw"
-                    st = stages[seg.stages[0]]
-                    p = params[seg.stages[0]]
-                    # execute the planned channel block verbatim —
-                    # re-planning here would silently ignore
-                    # policy.vmem_budget (and defeat measured autotuning,
-                    # which keys on the plan it timed)
-                    y = ops.dwconv2d(
-                        y, p["f"].astype(sdt), stride=st.stride,
-                        padding=st.padding,
-                        impl=impl, interpret=interpret,
-                        block_c=seg.plan.block_c,
-                        vmem_budget=policy.vmem_budget,
-                    )
-                    y = apply_epilogue(y, _cast(p.get("b"), sdt),
-                                       st.activation)
-                    if last:
-                        y = y.astype(k_out)
+                # compile-time only: names the segment in op metadata
+                with jax.named_scope(seg.kind):
+                    if seg.kind in ("fused3", "fused2"):
+                        y = _run_fused(seg, stages, params, y, seg_res,
+                                       impl=impl, interpret=interpret,
+                                       stream_dtype=sdt, out_dtype=k_out)
+                    elif seg.kind == "fusedmb":
+                        y = _run_fused_mb(seg, stages, params, y, seg_res,
+                                          impl=impl, interpret=interpret,
+                                          stream_dtype=sdt, out_dtype=k_out)
+                    elif seg.kind == "dw_se":
+                        y = _run_dw_se(seg, stages, params, y,
+                                       impl=impl, interpret=interpret,
+                                       stream_dtype=sdt, out_dtype=k_out)
+                    elif seg.kind == "se":
+                        y = _run_se(seg, stages, params, y, policy,
+                                    impl=impl, interpret=interpret,
+                                    stream_dtype=sdt, out_dtype=k_out)
+                    elif seg.kind == "mb":
+                        # standalone dense conv: XLA-lowered on every impl —
+                        # the dense conv is MXU-shaped as-is, the Pallas win is
+                        # the fused projection (segment kind "fusedmb")
+                        st = stages[seg.stages[0]]
+                        p = params[seg.stages[0]]
+                        y = ref.conv2d_ref(
+                            y, p["f"].astype(sdt), _cast(p.get("b"), sdt),
+                            stride=st.stride, padding=st.padding,
+                            activation=st.activation,
+                        ).astype(k_out)
+                    elif seg.kind == "pw":
+                        st = stages[seg.stages[0]]
+                        p = params[seg.stages[0]]
+                        y = ops.pwconv(
+                            y, p["w"].astype(sdt), _cast(p.get("b"), sdt),
+                            activation=st.activation,
+                            impl=impl, interpret=interpret,
+                            block_g=policy.block_g or seg.plan.block_g,
+                            block_co=policy.block_co or seg.plan.block_co,
+                            block_ci=policy.block_ci or seg.plan.block_c,
+                            vmem_budget=policy.vmem_budget,
+                            out_dtype=jnp.dtype(k_out).name,
+                        )
+                    else:  # "dw"
+                        st = stages[seg.stages[0]]
+                        p = params[seg.stages[0]]
+                        # execute the planned channel block verbatim —
+                        # re-planning here would silently ignore
+                        # policy.vmem_budget (and defeat measured autotuning,
+                        # which keys on the plan it timed)
+                        y = ops.dwconv2d(
+                            y, p["f"].astype(sdt), stride=st.stride,
+                            padding=st.padding,
+                            impl=impl, interpret=interpret,
+                            block_c=seg.plan.block_c,
+                            vmem_budget=policy.vmem_budget,
+                        )
+                        y = apply_epilogue(y, _cast(p.get("b"), sdt),
+                                           st.activation)
+                        if last:
+                            y = y.astype(k_out)
             except Exception as e:
                 # tag recognized backend failures with the segment that
                 # produced them (the runtime ladder keys its quarantine

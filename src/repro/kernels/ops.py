@@ -41,9 +41,10 @@ def pad_same(x: jax.Array, hf: int, wf: int, stride: int) -> jax.Array:
     wo = -(-wi // stride)
     ph = max((ho - 1) * stride + hf - hi, 0)
     pw = max((wo - 1) * stride + wf - wi, 0)
-    return jnp.pad(
-        x, ((0, 0), (ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2), (0, 0))
-    )
+    # compile-time only: names the pad in op metadata
+    with jax.named_scope("same_pad"):
+        return jnp.pad(x, ((0, 0), (ph // 2, ph - ph // 2),
+                           (pw // 2, pw - pw // 2), (0, 0)))
 
 
 _pad_same = pad_same

@@ -181,5 +181,6 @@ def pwconv_pallas(
         scratch_shapes=[pltpu.VMEM((bg, bco), jnp.float32)],
         compiler_params=compiler_params(model),
         interpret=interpret,
+        name="pwconv",
     )(*inputs)
     return out[:g, :co]

@@ -209,4 +209,5 @@ def dw_se_pallas(
         scratch_shapes=taps.stage_shapes((hiu, wiu, c), stride > 1),
         compiler_params=compiler_params(model),
         interpret=interpret,
+        name="dw_se",
     )(*inputs)

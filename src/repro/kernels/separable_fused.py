@@ -421,5 +421,6 @@ def separable_fused_pallas(
         scratch_shapes=[pltpu.VMEM((sh * wo, cob), jnp.float32)] + stage,
         compiler_params=compiler_params(model),
         interpret=interpret,
+        name="fused3" if expand_w is not None else "fused2",
     )(*inputs)
     return out[:, :ho, :, :co]

@@ -12,7 +12,9 @@ library, and pytest-xdist imports this file in every worker.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +109,35 @@ def test_standalone_pw_compiles_for_v5e(one_chip, g, ci, co):
     x = _sds((g, ci), jnp.float32, one_chip)
     w = _sds((ci, co), jnp.float32, one_chip)
     assert _tpu_calls(pwconv_pallas, x, w) == 1
+
+
+def test_body_names_its_blocks_and_kernels_for_v5e(one_chip):
+    """The first three V2 blocks (fused2, fused3 stride 2, fused3 with a
+    residual) through ``build_network_fn``: each op's metadata names its
+    block, segment kind and SAME pad, and each Pallas kernel is named by
+    its segment kind."""
+    full = network.mobilenet_v2_spec(1.0)
+    net = dataclasses.replace(full, blocks=full.blocks[:3])
+    pol = KernelPolicy(impl="pallas", on_failure="raise",
+                       dtype_policy=DtypePolicy(stream="bfloat16"))
+    shape = (1, RES, RES, net.c_in)
+    nplan = network.plan_network(net, shape, dtype=jnp.bfloat16, policy=pol)
+    params = [[{k: _sds(v.shape, jnp.bfloat16, one_chip)
+                for k, v in p.items()}
+               for p in param_structs(spec, bshape[-1], jnp.bfloat16)]
+              for spec, bshape in zip(net.blocks, nplan.block_shapes)]
+    text = jax.jit(network.build_network_fn(net, nplan, pol)).lower(
+        params, _sds(shape, jnp.bfloat16, one_chip)).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for block, kind in (("b00", "fused2"), ("b01", "fused3"),
+                        ("b02", "fused3")):
+        assert any(f"/{block}/{kind}/same_pad/" in n for n in op_names), \
+            (block, kind)
+    kernels = re.findall(r'^\s*(?:ROOT\s+)?%([\w.\-]+) = .*'
+                         r'custom_call_target="tpu_custom_call".*'
+                         r'op_name="([^"]*)"', text, re.MULTILINE)
+    assert [(name.split(".")[0], op.split("/")[1:3]) for name, op in
+            kernels] == [("fused2", ["b00", "fused2"]),
+                         ("fused3", ["b01", "fused3"]),
+                         ("fused3", ["b02", "fused3"])]
+    assert all(op.endswith("/pallas_call") for _, op in kernels)
