@@ -107,6 +107,7 @@ def segment_kernel_model(geom: _SegGeom, plan: BlockPlan,
             slab_h=plan.slab_h, itemsize=nb, out_itemsize=nb,
             has_expand=geom.kind == "fused3", has_dw_bias=True,
             has_pw_bias=True, has_residual=geom.residual,
+            pads=blocking.kernel_pads(geom.pads, geom.ho, plan.slab_h),
         )
     if geom.kind == "dw":
         hiu = (geom.ho - 1) * geom.stride + geom.hf
@@ -134,14 +135,15 @@ def _claimed_vmem(geom: _SegGeom, plan: BlockPlan,
                   ) -> int:
     """The planner's own model recomputed at the plan's block fields."""
     nb = plan.dtype_bytes
+    halo = blocking.kernel_pads(geom.pads, geom.ho, plan.slab_h)
     if geom.kind == "fused3":
         return blocking.fused3_vmem_bytes(
             geom.wo, plan.slab_h, geom.ci, plan.block_c, plan.block_co,
-            geom.hf, geom.wf, geom.stride, nb, geom.residual)
+            geom.hf, geom.wf, geom.stride, nb, geom.residual, halo)
     if geom.kind == "fused2":
         return blocking.fused_vmem_bytes(
             geom.wo, plan.slab_h, plan.block_c, plan.block_co,
-            geom.hf, geom.wf, geom.stride, nb, geom.residual)
+            geom.hf, geom.wf, geom.stride, nb, geom.residual, halo)
     if geom.kind == "fusedmb":
         return blocking.fused_mb_vmem_bytes(
             geom.wo, plan.slab_h, geom.ci, plan.block_c, plan.block_co,

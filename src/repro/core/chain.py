@@ -80,6 +80,12 @@ class DW:
         return ((h - self.hf) // self.stride + 1,
                 (w - self.wf) // self.stride + 1)
 
+    def same_pads(self, h: int, w: int) -> Optional[blocking.Pads]:
+        """The SAME padding of an ``h x w`` input (None when VALID)."""
+        if self.padding.lower() != "same":
+            return None
+        return blocking.same_pads(h, w, self.hf, self.wf, self.stride)
+
 
 @dataclasses.dataclass(frozen=True)
 class SE:
@@ -368,7 +374,8 @@ def plan(spec: SeparableSpec, x_shape: Sequence[int], *,
             p3 = blocking.plan_separable3(
                 ho, wo, c, stages[i].features, proj.features,
                 stride=d.stride, hf=d.hf, wf=d.wf, dtype=dtype,
-                vmem_budget=budget, residual=with_res)
+                vmem_budget=budget, residual=with_res,
+                pads=d.same_pads(h, w))
             if p3 is not None:
                 segments.append(ChainSegment("fused3", (i, i + 1, i + 2), p3))
                 h, w, c = ho, wo, proj.features
@@ -394,7 +401,7 @@ def plan(spec: SeparableSpec, x_shape: Sequence[int], *,
             p2 = blocking.plan_separable(
                 ho, wo, c, proj.features, stride=d.stride, hf=d.hf,
                 wf=d.wf, dtype=dtype, vmem_budget=budget,
-                residual=with_res)
+                residual=with_res, pads=d.same_pads(h, w))
             if p2 is not None:
                 segments.append(ChainSegment("fused2", (i, i + 1), p2))
                 h, w, c = ho, wo, proj.features

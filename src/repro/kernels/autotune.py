@@ -268,6 +268,7 @@ class _SegGeom:
     wf: int
     g: int         # GEMM rows (pw); SE reduced width (dw_se / se)
     residual: bool  # the folded residual rides this segment's kernel
+    pads: Optional[blocking.Pads] = None  # SAME pads (fused2 / fused3)
 
 
 def _segment_geoms(stages, cp: ChainPlan,
@@ -283,13 +284,14 @@ def _segment_geoms(stages, cp: ChainPlan,
             ho, wo = d.out_dims(h, w)
             geoms.append(_SegGeom("fused3", ho, wo, c, ex.features,
                                   proj.features, d.stride, d.hf, d.wf, 0,
-                                  with_res))
+                                  with_res, d.same_pads(h, w)))
             h, w, c = ho, wo, proj.features
         elif seg.kind == "fused2":
             d, proj = (stages[i] for i in seg.stages)
             ho, wo = d.out_dims(h, w)
             geoms.append(_SegGeom("fused2", ho, wo, c, c, proj.features,
-                                  d.stride, d.hf, d.wf, 0, with_res))
+                                  d.stride, d.hf, d.wf, 0, with_res,
+                                  d.same_pads(h, w)))
             h, w, c = ho, wo, proj.features
         elif seg.kind == "fusedmb":
             mb, proj = (stages[i] for i in seg.stages)
@@ -352,13 +354,13 @@ def segment_candidates(geom: _SegGeom, base: BlockPlan, dtype,
                               block_co=cob, slab_h=slab_h,
                               stride=geom.stride, hf=geom.hf, wf=geom.wf,
                               dtype=dtype, vmem_budget=vmem_budget,
-                              residual=geom.residual)
+                              residual=geom.residual, pads=geom.pads)
                 else:
                     p = probe(geom.ho, geom.wo, geom.c, geom.co,
                               block_co=cob, slab_h=slab_h,
                               stride=geom.stride, hf=geom.hf, wf=geom.wf,
                               dtype=dtype, vmem_budget=vmem_budget,
-                              residual=geom.residual)
+                              residual=geom.residual, pads=geom.pads)
                 if p is not None and p not in cands:
                     cands.append(p)
     elif geom.kind == "fusedmb":

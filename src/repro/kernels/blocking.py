@@ -45,7 +45,7 @@ the planner's choices).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -58,6 +58,9 @@ ACC_BYTES = 4
 
 #: TPU lane count — the minor-dim vector width every block snaps to.
 LANES = 128
+
+#: SAME padding amounts ``((top, bottom), (left, right))``.
+Pads = Tuple[Tuple[int, int], Tuple[int, int]]
 
 
 def dtype_bytes(dtype) -> int:
@@ -141,6 +144,31 @@ def slab_candidates(ho: int) -> list[int]:
     return sorted(cands, reverse=True)
 
 
+def same_pads(h: int, w: int, hf: int, wf: int, stride: int) -> Pads:
+    """TF SAME padding of an ``h x w`` input: ``ceil(h / stride)`` output
+    rows, the odd pad row (and column) at the bottom (right)."""
+    ho, wo = -(-h // stride), -(-w // stride)
+    ph = max((ho - 1) * stride + hf - h, 0)
+    pw = max((wo - 1) * stride + wf - w, 0)
+    return (ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2)
+
+
+def kernel_pads(pads: Optional[Pads], ho: int,
+                slab_h: int) -> Optional[Pads]:
+    """``pads`` when the fused separable kernel makes the SAME halo itself,
+    in VMEM, from the unpadded input; None when its input arrives padded
+    from HBM.  The kernel makes it for a plan of one row slab: a slabbed
+    plan's windows would each need their halo rows fetched by hand."""
+    return pads if pads is not None and slab_h >= ho else None
+
+
+def _unpadded(rows: int, cols: int, halo: Optional[Pads]) -> Tuple[int, int]:
+    """The ``rows x cols`` padded window less the ``halo`` made in VMEM."""
+    if halo is None:
+        return rows, cols
+    return rows - sum(halo[0]), cols - sum(halo[1])
+
+
 # ---------------------------------------------------------------------------
 # dwconv2d
 # ---------------------------------------------------------------------------
@@ -177,11 +205,14 @@ def plan_dwconv2d(hi: int, wi: int, ho: int, wo: int, c: int,
 
 def fused_vmem_bytes(wo: int, slab_h: int, cb: int, cob: int,
                      hf: int = 3, wf: int = 3, stride: int = 1,
-                     itemsize: int = 4, residual: bool = False) -> int:
+                     itemsize: int = 4, residual: bool = False,
+                     halo: Optional[Pads] = None) -> int:
     """Working-set bytes of the fused kernel at blocks
     ``(cb, cob, slab_h)``: fp32 accumulator + output tile (+ 2x residual
     tile), and per channel slab the 2x double-buffered input slab, the DW
     intermediate (fp32 value), the filter tile and 2x the PW weight tile.
+    ``halo`` is the SAME padding the kernel makes in VMEM
+    (:func:`kernel_pads`): the streamed input slab is that much smaller.
     The single source of truth for :func:`plan_separable` and
     ``benchmarks/kernel_vmem.py``."""
     slab_hi = (slab_h - 1) * stride + hf
@@ -189,7 +220,8 @@ def fused_vmem_bytes(wo: int, slab_h: int, cb: int, cob: int,
     out_side = slab_h * wo * cob * (ACC_BYTES + itemsize)
     if residual:
         out_side += 2 * slab_h * wo * cob * itemsize
-    per_c = (2 * slab_hi * wiu * itemsize       # input slab, double-buffered
+    hin, win = _unpadded(slab_hi, wiu, halo)
+    per_c = (2 * hin * win * itemsize           # input slab, double-buffered
              + hf * wf * itemsize               # DW filter tile
              + slab_h * wo * ACC_BYTES          # DW intermediate (fp32 value)
              + 2 * cob * itemsize)              # PW weight tile, dbl-buffered
@@ -199,12 +231,12 @@ def fused_vmem_bytes(wo: int, slab_h: int, cb: int, cob: int,
 def _fused_plan_at(ho: int, wo: int, c: int, slab_h: int, cob: int,
                    hf: int, wf: int, stride: int, itemsize: int,
                    residual: bool, vmem_budget: int,
-                   min_cb: int) -> Optional[int]:
+                   min_cb: int, halo: Optional[Pads]) -> Optional[int]:
     """Largest snapped channel block >= min_cb fitting the budget, or None."""
     base = fused_vmem_bytes(wo, slab_h, 0, cob, hf, wf, stride, itemsize,
-                            residual)
+                            residual, halo)
     per_c = fused_vmem_bytes(wo, slab_h, 1, cob, hf, wf, stride, itemsize,
-                             residual) - base
+                             residual, halo) - base
     rem = vmem_budget - base
     if rem < per_c:
         return None
@@ -217,15 +249,17 @@ def plan_separable_at(ho: int, wo: int, c: int, co: int, *,
                       stride: int = 1, hf: int = 3, wf: int = 3,
                       dtype=jnp.float32,
                       vmem_budget: int = DEFAULT_VMEM_BUDGET,
-                      residual: bool = False) -> Optional[BlockPlan]:
+                      residual: bool = False,
+                      pads: Optional[Pads] = None) -> Optional[BlockPlan]:
     """Feasibility probe at an EXPLICIT ``(block_co, slab_h)`` point: the
     largest channel block that fits the budget there, or None.  This is the
     autotuner's candidate constructor (``kernels/autotune.py``) — the
     analytic :func:`plan_separable` walks the same ladder but stops at the
     first hit; the tuner instead measures several feasible points."""
     nb = dtype_bytes(dtype)
+    halo = kernel_pads(pads, ho, slab_h)
     cb = _fused_plan_at(ho, wo, c, slab_h, block_co, hf, wf, stride, nb,
-                        residual, vmem_budget, 1)
+                        residual, vmem_budget, 1, halo)
     if cb is None:
         return None
     n_slabs = -(-ho // slab_h)
@@ -233,7 +267,7 @@ def plan_separable_at(ho: int, wo: int, c: int, co: int, *,
         block_c=cb, block_co=block_co, slab_h=slab_h, n_slabs=n_slabs,
         halo_rows=max(hf - stride, 0) if n_slabs > 1 else 0,
         vmem_bytes=fused_vmem_bytes(wo, slab_h, cb, block_co, hf, wf,
-                                    stride, nb, residual),
+                                    stride, nb, residual, halo),
         dtype_bytes=nb,
     )
 
@@ -242,7 +276,8 @@ def plan_separable(ho: int, wo: int, c: int, co: int, *,
                    stride: int = 1, hf: int = 3, wf: int = 3,
                    dtype=jnp.float32,
                    vmem_budget: int = DEFAULT_VMEM_BUDGET,
-                   residual: bool = False) -> Optional[BlockPlan]:
+                   residual: bool = False,
+                   pads: Optional[Pads] = None) -> Optional[BlockPlan]:
     """Block plan for the fused separable kernel, or None when nothing fits.
 
     Preference order (traffic-motivated, DESIGN.md §3):
@@ -258,6 +293,9 @@ def plan_separable(ho: int, wo: int, c: int, co: int, *,
 
     Returns None only when even ``(cb=1, cob=1, slab_h=1)`` exceeds the
     budget — with row slabs there is no resolution-driven ceiling anymore.
+    ``pads`` are the SAME pads of a padded segment's input
+    (:func:`same_pads`); a one-slab plan budgets the unpadded window the
+    kernel then reads (:func:`kernel_pads`).
     """
     nb = dtype_bytes(dtype)
     halo = max(hf - stride, 0)
@@ -267,8 +305,9 @@ def plan_separable(ho: int, wo: int, c: int, co: int, *,
     for cob in co_candidates(co):
         for min_cb in (min(c, LANES), 1):
             for slab_h in slab_candidates(ho):
+                vh = kernel_pads(pads, ho, slab_h)
                 cb = _fused_plan_at(ho, wo, c, slab_h, cob, hf, wf, stride,
-                                    nb, residual, vmem_budget, min_cb)
+                                    nb, residual, vmem_budget, min_cb, vh)
                 if cb is None:
                     continue
                 n_slabs = -(-ho // slab_h)
@@ -277,7 +316,8 @@ def plan_separable(ho: int, wo: int, c: int, co: int, *,
                     n_slabs=n_slabs,
                     halo_rows=halo if n_slabs > 1 else 0,
                     vmem_bytes=fused_vmem_bytes(
-                        wo, slab_h, cb, cob, hf, wf, stride, nb, residual),
+                        wo, slab_h, cb, cob, hf, wf, stride, nb, residual,
+                        vh),
                     dtype_bytes=nb,
                 )
     return None
@@ -289,7 +329,8 @@ def plan_separable(ho: int, wo: int, c: int, co: int, *,
 
 def fused3_vmem_bytes(wo: int, slab_h: int, ci: int, cb: int, cob: int,
                       hf: int = 3, wf: int = 3, stride: int = 1,
-                      itemsize: int = 4, residual: bool = False) -> int:
+                      itemsize: int = 4, residual: bool = False,
+                      halo: Optional[Pads] = None) -> int:
     """Working-set bytes of the 3-stage fused kernel (expand-on-the-fly) at
     blocks ``(cb, cob, slab_h)`` with raw-input channels ``ci``.
 
@@ -297,15 +338,18 @@ def fused3_vmem_bytes(wo: int, slab_h: int, ci: int, cb: int, cob: int,
     ``ci`` channels (fetched whole per grid cell — it is the expand GEMM's
     A-operand), and each expanded-channel slab adds the expand-weight tile
     ``(ci, cb)`` plus the fp32 expanded value ``(slab_hi, wiu, cb)`` that
-    replaces the streamed input as the DW stage's operand.  Single source of
-    truth for :func:`plan_separable3` and ``benchmarks/kernel_vmem.py``.
+    replaces the streamed input as the DW stage's operand.  With a ``halo``
+    made in VMEM (:func:`fused_vmem_bytes`) the raw input is unpadded; the
+    expanded value still fills the padded window.  Single source of truth
+    for :func:`plan_separable3` and ``benchmarks/kernel_vmem.py``.
     """
     slab_hi = (slab_h - 1) * stride + hf
     wiu = (wo - 1) * stride + wf
     out_side = slab_h * wo * cob * (ACC_BYTES + itemsize)
     if residual:
         out_side += 2 * slab_h * wo * cob * itemsize
-    out_side += 2 * slab_hi * wiu * ci * itemsize  # raw input, dbl-buffered
+    hin, win = _unpadded(slab_hi, wiu, halo)
+    out_side += 2 * hin * win * ci * itemsize  # raw input, dbl-buffered
     per_c = (2 * ci * itemsize                 # expand W tile, dbl-buffered
              + slab_hi * wiu * ACC_BYTES       # expanded value (fp32, VMEM)
              + hf * wf * itemsize              # DW filter tile
@@ -317,12 +361,12 @@ def fused3_vmem_bytes(wo: int, slab_h: int, ci: int, cb: int, cob: int,
 def _fused3_plan_at(c: int, ci: int, slab_h: int, cob: int, wo: int,
                     hf: int, wf: int, stride: int, itemsize: int,
                     residual: bool, vmem_budget: int,
-                    min_cb: int) -> Optional[int]:
+                    min_cb: int, halo: Optional[Pads]) -> Optional[int]:
     """Largest snapped expanded-channel block >= min_cb that fits, or None."""
     base = fused3_vmem_bytes(wo, slab_h, ci, 0, cob, hf, wf, stride,
-                             itemsize, residual)
+                             itemsize, residual, halo)
     per_c = fused3_vmem_bytes(wo, slab_h, ci, 1, cob, hf, wf, stride,
-                              itemsize, residual) - base
+                              itemsize, residual, halo) - base
     rem = vmem_budget - base
     if rem < per_c:
         return None
@@ -335,12 +379,14 @@ def plan_separable3_at(ho: int, wo: int, ci: int, c: int, co: int, *,
                        stride: int = 1, hf: int = 3, wf: int = 3,
                        dtype=jnp.float32,
                        vmem_budget: int = DEFAULT_VMEM_BUDGET,
-                       residual: bool = False) -> Optional[BlockPlan]:
+                       residual: bool = False,
+                       pads: Optional[Pads] = None) -> Optional[BlockPlan]:
     """3-stage analogue of :func:`plan_separable_at`: feasibility probe for
     the expand-on-the-fly kernel at an explicit ``(block_co, slab_h)``."""
     nb = dtype_bytes(dtype)
+    halo = kernel_pads(pads, ho, slab_h)
     cb = _fused3_plan_at(c, ci, slab_h, block_co, wo, hf, wf, stride, nb,
-                         residual, vmem_budget, 1)
+                         residual, vmem_budget, 1, halo)
     if cb is None:
         return None
     n_slabs = -(-ho // slab_h)
@@ -348,7 +394,7 @@ def plan_separable3_at(ho: int, wo: int, ci: int, c: int, co: int, *,
         block_c=cb, block_co=block_co, slab_h=slab_h, n_slabs=n_slabs,
         halo_rows=max(hf - stride, 0) if n_slabs > 1 else 0,
         vmem_bytes=fused3_vmem_bytes(wo, slab_h, ci, cb, block_co, hf, wf,
-                                     stride, nb, residual),
+                                     stride, nb, residual, halo),
         dtype_bytes=nb,
     )
 
@@ -357,7 +403,8 @@ def plan_separable3(ho: int, wo: int, ci: int, c: int, co: int, *,
                     stride: int = 1, hf: int = 3, wf: int = 3,
                     dtype=jnp.float32,
                     vmem_budget: int = DEFAULT_VMEM_BUDGET,
-                    residual: bool = False) -> Optional[BlockPlan]:
+                    residual: bool = False,
+                    pads: Optional[Pads] = None) -> Optional[BlockPlan]:
     """Block plan for the 3-stage fused chain (expand -> DW -> project), or
     None when nothing fits (callers degrade to the 2-stage plan:
     standalone expand GEMM + :func:`plan_separable`, then to unfused).
@@ -368,14 +415,16 @@ def plan_separable3(ho: int, wo: int, ci: int, c: int, co: int, *,
     expanded-channel slab, full-lane if possible.  The expanded intermediate
     dominates the budget (fp32 ``(slab_hi, wiu, cb)`` per reduction step),
     so high resolutions slab earlier than the 2-stage kernel does.
+    ``pads`` as in :func:`plan_separable`.
     """
     nb = dtype_bytes(dtype)
     halo = max(hf - stride, 0)
     for cob in co_candidates(co):
         for min_cb in (min(c, LANES), 1):
             for slab_h in slab_candidates(ho):
+                vh = kernel_pads(pads, ho, slab_h)
                 cb = _fused3_plan_at(c, ci, slab_h, cob, wo, hf, wf, stride,
-                                     nb, residual, vmem_budget, min_cb)
+                                     nb, residual, vmem_budget, min_cb, vh)
                 if cb is None:
                     continue
                 n_slabs = -(-ho // slab_h)
@@ -385,7 +434,7 @@ def plan_separable3(ho: int, wo: int, ci: int, c: int, co: int, *,
                     halo_rows=halo if n_slabs > 1 else 0,
                     vmem_bytes=fused3_vmem_bytes(
                         wo, slab_h, ci, cb, cob, hf, wf, stride, nb,
-                        residual),
+                        residual, vh),
                     dtype_bytes=nb,
                 )
     return None

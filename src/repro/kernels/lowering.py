@@ -9,6 +9,12 @@ executables:
   (expand-on-the-fly, neither intermediate in HBM);
 * ``fused2`` segments -> ``separable_fused_pallas`` (the PR-2 DW -> PW
   kernel);
+* SAME padding of a fused segment: a one-slab plan hands the kernel the
+  unpadded input and its ``pads`` (the kernel makes the halo in VMEM); a
+  slabbed plan pads the input in HBM first (``ops.pad_same``), as the
+  ``fusedmb`` and ``dw_se`` segments still do.  The always-on telemetry
+  counters ``lowering.halo_in_kernel`` / ``lowering.halo_padded`` count
+  the two fused outcomes at trace time;
 * ``pw`` / ``dw`` segments -> the standalone ``ops.pwconv`` /
   ``ops.dwconv2d`` kernels;
 * on the XLA backend every fused segment runs ``ref.separable_fused_ref``
@@ -19,9 +25,9 @@ shapes its ``ChainSegment.plan`` carries, so a ``ChainPlan`` is a complete,
 reproducible execution recipe (and therefore a cacheable autotuning unit).
 
 Stage objects are duck-typed (``features``/``activation``/``bias`` for PW,
-``stride``/``hf``/``wf``/``padding``/``activation``/``bias`` for DW) so this
-module depends only on the kernel layer; the spec dataclasses live in
-``core/chain.py``.
+``stride``/``hf``/``wf``/``padding``/``same_pads``/``activation``/``bias``
+for DW) so this module depends only on the kernel layer; the spec
+dataclasses live in ``core/chain.py``.
 
 The dtype policy (``KernelPolicy.dtype_policy``, DESIGN.md §7) is applied
 HERE, once per chain: the input and every parameter leaf are cast to the
@@ -37,14 +43,14 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.kernels import blocking, ops, ref
 from repro.kernels.blocking import ChainPlan
 from repro.kernels.epilogue import apply_epilogue
 from repro.kernels.fused_mbconv import fused_mbconv_pallas
 from repro.kernels.policy import DEFAULT_POLICY, KernelPolicy
 from repro.kernels.se_epilogue import dw_se_pallas
 from repro.kernels.separable_fused import separable_fused_pallas
-from repro.runtime import failures, faultinject
+from repro.runtime import failures, faultinject, telemetry
 
 #: Per-stage parameter leaves the lowering consumes: PW stages take
 #: ``{"w": (Ci, Co)[, "b": (Co,)]}``, DW stages ``{"f": (Hf, Wf, C)[,
@@ -95,10 +101,16 @@ def _run_fused(seg, stages, params, y, res, *, impl, interpret,
             dw_activation=d.activation, activation=proj.activation,
         )
         return out.astype(out_dtype)
-    if d.padding.lower() == "same":
-        y = ops.pad_same(y, d.hf, d.wf, d.stride)
-    elif d.padding.lower() != "valid":
+    if d.padding.lower() not in ("same", "valid"):
         raise ValueError(d.padding)
+    _, h, w, _ = y.shape
+    pads = d.same_pads(h, w)
+    halo = blocking.kernel_pads(pads, d.out_dims(h, w)[0], seg.plan.slab_h)
+    if halo is not None:
+        telemetry.count("lowering.halo_in_kernel")
+    elif pads is not None:
+        telemetry.count("lowering.halo_padded")
+        y = ops.pad_same(y, d.hf, d.wf, d.stride)
     return separable_fused_pallas(
         y, dw_f, pw_w, dw_b, pw_b, res,
         expand_w=expand_w, expand_activation=expand_act,
@@ -106,7 +118,7 @@ def _run_fused(seg, stages, params, y, res, *, impl, interpret,
         activation=proj.activation,
         block_c=seg.plan.block_c, block_co=seg.plan.block_co,
         slab_h=seg.plan.slab_h, interpret=interpret,
-        out_dtype=jnp.dtype(out_dtype).name,
+        out_dtype=jnp.dtype(out_dtype).name, pads=halo,
     )
 
 

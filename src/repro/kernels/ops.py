@@ -31,20 +31,17 @@ _resolve = resolve_impl
 
 
 def pad_same(x: jax.Array, hf: int, wf: int, stride: int) -> jax.Array:
-    """Explicit SAME padding (so the Pallas kernels only see VALID).
+    """Explicit SAME padding in HBM (so the Pallas kernels only see VALID).
 
-    Public: the chain lowering (kernels/lowering.py) applies it before
-    handing fused segments to the VALID-geometry kernels.
+    Public: the chain lowering (kernels/lowering.py) applies it before a
+    ``fused2``/``fused3`` segment of more than one row slab (one slab makes
+    its halo in the kernel) and before ``fusedmb`` and ``dw_se`` segments.
     """
-    _, hi, wi, _ = x.shape
-    ho = -(-hi // stride)
-    wo = -(-wi // stride)
-    ph = max((ho - 1) * stride + hf - hi, 0)
-    pw = max((wo - 1) * stride + wf - wi, 0)
+    (top, bottom), (left, right) = blocking.same_pads(
+        x.shape[1], x.shape[2], hf, wf, stride)
     # compile-time only: names the pad in op metadata
     with jax.named_scope("same_pad"):
-        return jnp.pad(x, ((0, 0), (ph // 2, ph - ph // 2),
-                           (pw // 2, pw - pw // 2), (0, 0)))
+        return jnp.pad(x, ((0, 0), (top, bottom), (left, right), (0, 0)))
 
 
 _pad_same = pad_same

@@ -51,11 +51,21 @@ VMEM fp32 scratch, applies the expand activation, and feeds that
 slab to the DW shift-and-FMA in place of the streamed input.  Neither the
 expanded tensor (``B*Hi*Wi*C`` — 6x the input at the usual expansion
 factor) nor the DW output ever exists in HBM.  Restriction: the expansion
-must be bias-free, because SAME padding is applied to the raw input before
-the kernel and a bias would make padding pixels expand to ``act(bias) != 0``
-(every supported activation maps 0 -> 0, so bias-free expand commutes with
-zero padding).  ``core/chain.plan`` degrades to the 2-stage path when the
-spec declares an expand bias.
+must be bias-free, because the SAME halo is zero in the RAW input's
+channels — the kernel writes zeros where a pad pixel's expansion would go
+(or expands the zero pixels the wrapper padded in), and a bias would make
+them ``act(bias) != 0`` (every supported activation maps 0 -> 0, so a
+bias-free expand commutes with zero padding).  ``core/chain.plan``
+degrades to the 2-stage path when the spec declares an expand bias.
+
+SAME padding (``pads``): a one-slab plan takes the UNPADDED input and makes
+the halo in VMEM — no padded copy of the block's input is written to HBM.
+The kernel writes its input (or its expanded rows) into the fp32 tap stage
+at the pad offset and zeroes the halo around it (``kernels/taps.py``); the
+taps then read the stage as they read a padded window.  The values are
+those of the padded input, so the output is unchanged.  Without ``pads``
+the geometry is VALID: the caller pads in HBM (``ops.pad_same``), as
+slabbed plans and the unfused ops still do.
 
 All block choices come from ``kernels.blocking.plan_separable`` /
 ``plan_separable3`` (dtype-aware VMEM budget, Co-panel and row-slab
@@ -66,8 +76,9 @@ returns None and callers fall back to the unfused composition
 TPU note: the overlapping input windows use element-offset indexing
 (``pl.Element`` on every block dim, ``gridspec.in_specs_from_model``); the
 row offset is un-tiled and free, the lane offset must be provably
-128-aligned (MC204).  The DW taps of a strided or expanded block
-read an fp32 lane-chunked VMEM stage (``kernels/taps.py``).
+128-aligned (MC204).  The DW taps of a strided or expanded block, and of
+one that makes its SAME halo, read an fp32 lane-chunked VMEM stage
+(``kernels/taps.py``).
 """
 from __future__ import annotations
 
@@ -87,12 +98,19 @@ from repro.kernels.gridspec import (BlockRef, KernelModel,
 from repro.kernels.policy import contract_precision
 
 
+def _staged(has_expand: bool, stride: int, pads) -> bool:
+    """Whether the DW taps read an fp32 stage (``taps.py``): the expanded
+    slab, a strided window, or a window whose SAME halo is made in VMEM."""
+    return has_expand or stride > 1 or pads is not None
+
+
 def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
                        co: int, hf: int, wf: int, stride: int,
                        block_c: int, block_co: int, slab_h: int,
                        itemsize: int, out_itemsize: int,
                        has_expand: bool, has_dw_bias: bool,
-                       has_pw_bias: bool, has_residual: bool) -> KernelModel:
+                       has_pw_bias: bool, has_residual: bool,
+                       pads=None) -> KernelModel:
     """The exact grid/BlockSpec geometry ``separable_fused_pallas`` lowers
     to at these blocks — the single source of truth consumed by BOTH the
     kernel (specs built from this model) and the static analyzer
@@ -101,7 +119,9 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
 
     ``c_in`` is the raw input channel count (== ``c`` without expand).
     Shapes are the PADDED shapes the kernel hands to ``pl.pallas_call``
-    after channel/Co/row padding.
+    after channel/Co/row padding.  With ``pads`` (the SAME padding made in
+    VMEM, one row slab) the input is the unpadded image, one whole block
+    per image.
     """
     cb, cob = block_c, block_co
     sh = min(slab_h, ho)
@@ -115,12 +135,21 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
     nk = cp // cb
     rows_in = (ho_p - 1) * stride + hf
 
+    if pads is not None:
+        assert n_slabs == 1, "the SAME halo is made in VMEM for one slab"
+        hin, win = slab_hi - sum(pads[0]), wiu - sum(pads[1])
+        if has_expand:
+            x_ref = BlockRef("x", (b, hin, win, c_in), (1, hin, win, c_in),
+                             lambda i, s, j, k: (i, 0, 0, 0), itemsize)
+        else:
+            x_ref = BlockRef("x", (b, hin, win, cp), (1, hin, win, cb),
+                             lambda i, s, j, k: (i, 0, 0, k), itemsize)
     # x window: element-offset (unblocked) indexing — adjacent slabs'
     # windows overlap by the (hf - stride)-row halo.  With expand the
     # window carries ALL raw channels; without, one channel slab.  A single
     # channel slab gets a literal 0 lane offset: Mosaic must prove the
     # offset 128-aligned, and cannot for ``k * cb`` with cb < 128 (MC204).
-    if has_expand:
+    elif has_expand:
         x_ref = BlockRef(
             "x", (b, rows_in, wiu, c_in), (1, slab_hi, wiu, c_in),
             lambda i, s, j, k, sh=sh, st=stride: (i, s * sh * st, 0, 0),
@@ -161,7 +190,7 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
         output=out_ref,
         scratch_bytes=(sh * wo * cob * 4           # fp32 accumulator
                        + taps.stage_bytes((slab_hi, wiu, cb),
-                                          has_expand or stride > 1)),
+                                          _staged(has_expand, stride, pads))),
         value_bytes=sh * wo * cb * 4,              # DW intermediate (fp32)
         reshapes=tuple(reshapes),
     )
@@ -170,17 +199,18 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
 def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
                   dw_activation, activation, has_exp: bool,
                   expand_activation, has_dwb: bool, has_pwb: bool,
-                  has_res: bool, out_dtype):
+                  has_res: bool, out_dtype, pads):
     """refs = (x, [expand_w,] f, [dw_bias,] w, [pw_bias,] [residual,] out,
     acc, [stage]).
 
     Blocks: x (1, slab_hi, Wiu, Cb) — the overlapping input window of this
     row slab (with expand: (1, slab_hi, Wiu, Ci), the RAW input, identical
-    for every reduction step); expand_w (Ci, Cb); f (Hf, Wf, Cb); dw_bias
-    (1, Cb); w (Cb, Cob); pw_bias (1, Cob); residual (1, slab_h, Wo, Cob);
-    out (1, slab_h, Wo, Cob); acc VMEM scratch (slab_h*Wo, Cob) fp32;
-    stage: the fp32 lane-chunked DW input (the expanded slab, or x when
-    strided — ``taps.py``).
+    for every reduction step; with ``pads``: the unpadded image);
+    expand_w (Ci, Cb); f (Hf, Wf, Cb); dw_bias (1, Cb); w (Cb, Cob);
+    pw_bias (1, Cob); residual (1, slab_h, Wo, Cob); out (1, slab_h, Wo,
+    Cob); acc VMEM scratch (slab_h*Wo, Cob) fp32; stage: the fp32
+    lane-chunked DW input window (the expanded slab, or x when strided or
+    when the kernel makes the SAME halo — ``taps.py``).
     """
     it = iter(refs)
     x_ref = next(it)
@@ -195,8 +225,11 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     stage_ref = next(it, None)
 
     _, slab_h, wo, cob = out_ref.shape
+    _, x_rows, x_cols, _ = x_ref.shape
     cb = f_ref.shape[2]
     k = pl.program_id(3)
+    # where the input sits in the stage: past the halo made here
+    top, left = (pads[0][0], pads[1][0]) if pads is not None else (0, 0)
 
     @pl.when(k == 0)
     def _init():
@@ -214,12 +247,15 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
             ex = jnp.dot(x_ref[0, h].astype(jnp.float32), ew,
                          preferred_element_type=jnp.float32, precision=prec)
             taps.stage(stage_ref, _epilogue(ex, None, expand_activation),
-                       row=h)
+                       row=h + top, col=left)
             return carry
 
-        jax.lax.fori_loop(0, x_ref.shape[1], expand_row, 0)
+        jax.lax.fori_loop(0, x_rows, expand_row, 0)
     elif stage_ref is not None:
-        taps.stage_input(stage_ref, x_ref)
+        taps.stage_input(stage_ref, x_ref, top, left)
+    if pads is not None:
+        # a pad pixel is zero, expanded or not (the expand is bias-free)
+        taps.zero_halo(stage_ref, pads, x_rows, x_cols)
 
     # --- DW stage: shift-and-FMA over the channel slab (dwconv2d Alg. 4) ---
     f = f_ref[...].astype(jnp.float32)
@@ -257,7 +293,7 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     jax.jit,
     static_argnames=("stride", "dw_activation", "activation",
                      "expand_activation", "block_c", "block_co", "slab_h",
-                     "interpret", "out_dtype"),
+                     "interpret", "out_dtype", "pads"),
 )
 def separable_fused_pallas(
     x: jax.Array,
@@ -277,6 +313,7 @@ def separable_fused_pallas(
     slab_h: int | None = None,
     interpret: bool = False,
     out_dtype: Optional[str] = None,
+    pads: Optional[blocking.Pads] = None,
 ) -> jax.Array:
     """Fused DW+PW block. x (B,Hi,Wi,C); dw_f (Hf,Wf,C); pw_w (C,Co)
     [+ dw_bias (C,), pw_bias (Co,), residual (B,Ho,Wo,Co)] -> (B,Ho,Wo,Co).
@@ -291,14 +328,19 @@ def separable_fused_pallas(
     policy's ``out`` dtype (DESIGN.md §7); ``None`` stores at ``x.dtype``.
     The accumulator is fp32 VMEM scratch regardless.
 
-    VALID geometry — SAME padding is applied by the wrapper (ops.py /
-    lowering.py).  Block shapes not given explicitly come from
+    ``pads`` ``((top, bottom), (left, right))`` — the SAME padding of the
+    unpadded ``x`` (``blocking.same_pads``) — makes the kernel apply it in
+    VMEM; the plan must then be one row slab (``blocking.kernel_pads``).
+    Without ``pads`` the geometry is VALID and a SAME caller pads ``x``
+    first (``ops.pad_same``).  Block shapes not given explicitly come from
     :func:`repro.kernels.blocking.plan_separable` (or ``plan_separable3``
     with expand); raises ValueError when even the minimal plan exceeds the
     VMEM budget (callers should have consulted the planner and taken a
     degraded path instead).
     """
     b, hi, wi, c_in = x.shape
+    if pads is not None:
+        hi, wi = hi + sum(pads[0]), wi + sum(pads[1])   # the padded extent
     odt = jnp.dtype(out_dtype) if out_dtype is not None else x.dtype
     hf, wf, cf = dw_f.shape
     cw, co = pw_w.shape
@@ -319,11 +361,11 @@ def separable_fused_pallas(
         if expand_w is not None:
             plan = blocking.plan_separable3(
                 ho, wo, c_in, c, co, stride=stride, hf=hf, wf=wf,
-                dtype=x.dtype, residual=residual is not None)
+                dtype=x.dtype, residual=residual is not None, pads=pads)
         else:
             plan = blocking.plan_separable(
                 ho, wo, c, co, stride=stride, hf=hf, wf=wf, dtype=x.dtype,
-                residual=residual is not None)
+                residual=residual is not None, pads=pads)
         if plan is None and (block_c is None or block_co is None):
             raise ValueError(
                 f"no fused block plan fits VMEM for {(hi, wi, c, co)}; "
@@ -338,6 +380,10 @@ def separable_fused_pallas(
     n_slabs = -(-ho // sh)
     ho_p = n_slabs * sh
     slab_hi = (sh - 1) * stride + hf
+    if pads is not None and blocking.kernel_pads(pads, ho, sh) is None:
+        raise ValueError(
+            f"the SAME halo is made in VMEM for one row slab, not {n_slabs};"
+            " pad the input in HBM (ops.pad_same) for a slabbed plan")
 
     # Channel / Co padding (zero rows of pw_w nullify padded DW channels;
     # with expand, zero COLUMNS of expand_w make the padded expanded
@@ -365,7 +411,8 @@ def separable_fused_pallas(
     # Row padding so the slab grid tiles Ho: the last slab's window reads
     # zero rows past the image and its garbage output rows are cropped.
     rows_in = (ho_p - 1) * stride + hf
-    x = x[:, :hiu, :wiu, :]
+    top, left = (pads[0][0], pads[1][0]) if pads is not None else (0, 0)
+    x = x[:, :hiu - top, :wiu - left, :]     # the rows and columns read
     if rows_in > hiu:
         x = jnp.pad(x, ((0, 0), (0, rows_in - hiu), (0, 0), (0, 0)))
     if ho_p > ho and residual is not None:
@@ -384,6 +431,7 @@ def separable_fused_pallas(
         itemsize=x.dtype.itemsize, out_itemsize=odt.itemsize,
         has_expand=expand_w is not None, has_dw_bias=dw_bias is not None,
         has_pw_bias=pw_bias is not None, has_residual=residual is not None,
+        pads=pads,
     )
     inputs = [x]
     if expand_w is not None:
@@ -406,10 +454,10 @@ def separable_fused_pallas(
         dw_activation=dw_activation, activation=activation,
         has_exp=expand_w is not None, expand_activation=expand_activation,
         has_dwb=dw_bias is not None, has_pwb=pw_bias is not None,
-        has_res=residual is not None, out_dtype=odt,
+        has_res=residual is not None, out_dtype=odt, pads=pads,
     )
     stage = taps.stage_shapes((slab_hi, wiu, cb),
-                              expand_w is not None or stride > 1)
+                              _staged(expand_w is not None, stride, pads))
 
     assert model.output.array_shape == (b, ho_p, wo, cop)
     out = pl.pallas_call(

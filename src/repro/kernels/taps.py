@@ -18,6 +18,13 @@ directly, and a strided one reads an fp32 **stage**: a VMEM scratch of shape
 split into lane chunks, written once per grid cell.  The staged values are
 exactly the fp32 upcast the taps would read, so the arithmetic — and the
 interpret-mode output — does not depend on which path a kernel takes.
+
+SAME padding made in VMEM (the fused separable kernel, DESIGN.md §3) stages
+the window at any stride: the kernel writes its unpadded input into the
+stage at the pad offset and zeroes the halo around it (:func:`zero_halo`),
+so out-of-image taps read exactly the zeros that padding in HBM put there.
+A unit-stride tap of a staged window reads the stage too: unaligned fp32
+rows, which ran faster on v5e than the packed bf16 input block (PERF.md).
 """
 from __future__ import annotations
 
@@ -54,21 +61,41 @@ def _chunks(stage_ref, lanes: int):
         yield j, j * width, min(width, lanes - j * width)
 
 
-def stage(stage_ref, value, row=slice(None)) -> None:
+def stage(stage_ref, value, row=slice(None), col: int = 0) -> None:
     """Write an fp32 ``(rows, cols, lanes)`` value — or, given a ``row``
-    index, one ``(cols, lanes)`` row — into the lane chunks (the tail
-    chunk's lanes past ``lanes`` are left unwritten and never leave
-    :func:`tap`)."""
+    index, one ``(cols, lanes)`` row — into the lane chunks, from column
+    ``col`` (the tail chunk's lanes past ``lanes`` are left unwritten and
+    never leave :func:`tap`)."""
+    cols = pl.ds(col, value.shape[-2])
     for j, lo, w in _chunks(stage_ref, value.shape[-1]):
-        stage_ref[j, row, :, :w] = value[..., lo:lo + w]
+        stage_ref[j, row, cols, :w] = value[..., lo:lo + w]
 
 
-def stage_input(stage_ref, x_ref) -> None:
+def stage_input(stage_ref, x_ref, top: int = 0, left: int = 0) -> None:
     """:func:`stage` for the ``(1, rows, cols, lanes)`` input block, read
-    and upcast one lane chunk at a time."""
-    for j, lo, w in _chunks(stage_ref, x_ref.shape[-1]):
-        stage_ref[j, :, :, :w] = x_ref[0, :, :, lo:lo + w].astype(
+    and upcast one lane chunk at a time, at row ``top`` and column
+    ``left`` of the stage."""
+    _, rows, cols, lanes = x_ref.shape
+    rs, cs = pl.ds(top, rows), pl.ds(left, cols)
+    for j, lo, w in _chunks(stage_ref, lanes):
+        stage_ref[j, rs, cs, :w] = x_ref[0, :, :, lo:lo + w].astype(
             jnp.float32)
+
+
+def zero_halo(stage_ref, pads, rows: int, cols: int) -> None:
+    """Zero the SAME halo ``pads`` ``((top, bottom), (left, right))``
+    around the ``rows x cols`` input written at ``(top, left)``: every
+    stage row above and below it, and the columns beside it."""
+    (top, bottom), (left, right) = pads
+    n, hp, wp, width = stage_ref.shape
+    for r0, nr in ((0, top), (top + rows, bottom)):
+        if nr:
+            stage_ref[:, pl.ds(r0, nr)] = jnp.zeros((n, nr, wp, width),
+                                                    jnp.float32)
+    for c0, nc in ((0, left), (left + cols, right)):
+        if nc:
+            stage_ref[:, :, pl.ds(c0, nc)] = jnp.zeros((n, hp, nc, width),
+                                                       jnp.float32)
 
 
 def tap(x_ref, stage_ref, n: int, m: int, rows: int, cols: int,

@@ -94,6 +94,12 @@ def record_quarantine_hit(*, scope: str, key: str, banned) -> None:
                        "key": key, "banned": sorted(banned)})
 
 
+def count(name: str) -> None:
+    """One more of the always-on counter ``name``."""
+    with _LOCK:
+        _COUNTERS[name] += 1
+
+
 def record_build(ns: int) -> None:
     """One ``execute_network`` memo miss that took ``ns`` nanoseconds."""
     with _LOCK:

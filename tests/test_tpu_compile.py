@@ -59,7 +59,8 @@ def _sds(shape, dtype, sharding):
 
 
 #: (body, block index, stream dtype): one planned block per distinct
-#: (segment kind, stride) the four bodies use, plus a bf16 stream case.
+#: (segment kind, stride) the four bodies use, plus bf16 stream cases.
+#: Every fused2 / fused3 block here makes its SAME halo in the kernel.
 BLOCKS = [
     ("mobilenet_v1_spec", 0, "fp32"),        # fused2 s1, 112x112x32
     ("mobilenet_v1_spec", 1, "fp32"),        # fused2 s2
@@ -70,6 +71,9 @@ BLOCKS = [
     ("efficientnet_lite0_spec", 2, "fp32"),  # fusedmb s1 + residual
     ("efficientnet_lite0_spec", 1, "fp32"),  # fusedmb s2
     ("mobilenet_v2_spec", 3, "bf16"),        # fused3 s2, bf16 stream
+    ("mobilenet_v2_spec", 2, "bf16"),        # fused3 s1 + residual, bf16
+    ("efficientnet_lite0_spec", 11, "bf16"),  # fused3 5x5 s2, pads (1, 2)
+    ("efficientnet_lite0_spec", 12, "fp32"),  # fused3 5x5 s1 at 7x7
 ]
 
 
@@ -111,11 +115,33 @@ def test_standalone_pw_compiles_for_v5e(one_chip, g, ci, co):
     assert _tpu_calls(pwconv_pallas, x, w) == 1
 
 
+@pytest.mark.parametrize("body", ["mobilenet_v1_spec", "mobilenet_v2_spec"])
+def test_body_has_no_pad_outside_its_kernels_for_v5e(one_chip, body):
+    """The V1 and V2 bodies at a 224 image, bf16 stream, batch 1, as one
+    program: one ``tpu_custom_call`` per planned Pallas pass and no HLO
+    ``pad`` beside them — every fused block makes its SAME halo in VMEM."""
+    net = getattr(network, body)(1.0)
+    pol = KernelPolicy(impl="pallas", on_failure="raise",
+                       dtype_policy=DtypePolicy(stream="bfloat16"))
+    shape = (1, RES, RES, net.c_in)
+    nplan = network.plan_network(net, shape, dtype=jnp.bfloat16, policy=pol)
+    params = [[{k: _sds(v.shape, jnp.bfloat16, one_chip)
+                for k, v in p.items()}
+               for p in param_structs(spec, bshape[-1], jnp.bfloat16)]
+              for spec, bshape in zip(net.blocks, nplan.block_shapes)]
+    text = jax.jit(network.build_network_fn(net, nplan, pol)).lower(
+        params, _sds(shape, jnp.bfloat16, one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        nplan.n_pallas_calls)
+    assert not re.findall(r"^\s*(?:ROOT\s+)?%\S+ = \S+ pad\(", text,
+                          re.MULTILINE)
+
+
 def test_body_names_its_blocks_and_kernels_for_v5e(one_chip):
     """The first three V2 blocks (fused2, fused3 stride 2, fused3 with a
     residual) through ``build_network_fn``: each op's metadata names its
-    block, segment kind and SAME pad, and each Pallas kernel is named by
-    its segment kind."""
+    block and segment kind, no SAME pad is left outside the kernels, and
+    each Pallas kernel is named by its segment kind."""
     full = network.mobilenet_v2_spec(1.0)
     net = dataclasses.replace(full, blocks=full.blocks[:3])
     pol = KernelPolicy(impl="pallas", on_failure="raise",
@@ -131,8 +157,8 @@ def test_body_names_its_blocks_and_kernels_for_v5e(one_chip):
     op_names = re.findall(r'op_name="([^"]*)"', text)
     for block, kind in (("b00", "fused2"), ("b01", "fused3"),
                         ("b02", "fused3")):
-        assert any(f"/{block}/{kind}/same_pad/" in n for n in op_names), \
-            (block, kind)
+        assert any(f"/{block}/{kind}/" in n for n in op_names), (block, kind)
+    assert not any("/same_pad/" in n for n in op_names)
     kernels = re.findall(r'^\s*(?:ROOT\s+)?%([\w.\-]+) = .*'
                          r'custom_call_target="tpu_custom_call".*'
                          r'op_name="([^"]*)"', text, re.MULTILINE)
