@@ -19,7 +19,28 @@ import jax.numpy as jnp
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-ACTIVATIONS = {None: lambda y: y, "relu6": lambda y: jnp.clip(y, 0.0, 6.0)}
+
+def sigmoid(y):
+    """The logistic function; where ``exp(-y)`` overflows to inf, ``1 /
+    inf`` is the limit 0."""
+    return 1.0 / (1.0 + jnp.exp(-y))
+
+
+def gelu_tanh(y):
+    """GELU, the tanh approximation (what the program's epilogue
+    applies)."""
+    return 0.5 * y * (1.0 + jnp.tanh(
+        math.sqrt(2.0 / math.pi) * (y + 0.044715 * y ** 3)))
+
+
+#: Every activation the program's epilogues accept, in plain jax.numpy.
+ACTIVATIONS = {
+    None: lambda y: y,
+    "relu": lambda y: jnp.maximum(y, 0.0),
+    "relu6": lambda y: jnp.clip(y, 0.0, 6.0),
+    "gelu": gelu_tanh,
+    "silu": lambda y: y * sigmoid(y),
+}
 
 
 def load_module(path):

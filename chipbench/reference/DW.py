@@ -4,6 +4,17 @@ then bias and activation."""
 import jax
 
 
+def describe(stage):
+    """The stage dict a configuration's ``blocks()`` gives for the
+    program's ``chain.DW`` stage ``stage``: only square SAME taps are
+    computed here."""
+    if stage.hf != stage.wf or stage.padding != "same":
+        raise ValueError(f"reference/DW.py computes square SAME depthwise "
+                         f"convs only; the program has {stage}")
+    return {"kind": "DW", "k": stage.hf, "stride": stage.stride,
+            "bias": stage.bias, "act": stage.activation}
+
+
 def params(st, c, gain):
     """{leaf: (shape, scale)}; each leaf is a standard normal draw times
     its scale, ``gain / sqrt(fan_in)`` for the taps."""

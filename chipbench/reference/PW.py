@@ -4,6 +4,13 @@ import jax
 import jax.numpy as jnp
 
 
+def describe(stage):
+    """The stage dict a configuration's ``blocks()`` gives for the
+    program's ``chain.PW`` stage ``stage``."""
+    return {"kind": "PW", "c_out": stage.features, "bias": stage.bias,
+            "act": stage.activation}
+
+
 def params(st, c, gain):
     """{leaf: (shape, scale)}; each leaf is a standard normal draw times
     its scale, ``gain / sqrt(fan_in)`` for the matrix."""

@@ -86,24 +86,30 @@ def load_cell(name, bench=None):
 
 def check_program_spec(net, bd):
     """The program's NetworkSpec must be the body this benchmark counts
-    and computes: same stages, widths, strides, biases and residuals."""
+    and computes: same stages, widths, strides, biases and residuals.
+    A program stage of class ``K`` reads as ``reference/K.py``'s
+    ``describe(stage)``."""
     if net.c_in != bd.in_shape[2] or net.n_blocks != len(bd.blocks):
         raise ValueError(f"program spec {net.name}: c_in {net.c_in}, "
                          f"{net.n_blocks} blocks; the config has "
                          f"{bd.in_shape[2]}, {len(bd.blocks)}")
+    refs = dict(bd.ref)
+
+    def describe(stage):
+        kind = type(stage).__name__
+        if kind not in refs:
+            path = os.path.join(HERE, "reference", f"{kind}.py")
+            if not os.path.isfile(path):
+                raise FileNotFoundError(
+                    f"program stage kind {kind} has no reference file: "
+                    f"add chipbench/reference/{kind}.py with describe(), "
+                    "params() and apply()")
+            refs[kind] = body_mod.load_module(path)
+        return refs[kind].describe(stage)
+
     c = net.c_in
     for i, (spec, blk) in enumerate(zip(net.blocks, bd.blocks)):
-        got = []
-        for s in spec.stages:
-            kind = type(s).__name__
-            if kind == "PW":
-                got.append({"kind": "PW", "c_out": s.features,
-                            "bias": s.bias, "act": s.activation})
-            elif kind == "DW" and s.hf == s.wf and s.padding == "same":
-                got.append({"kind": "DW", "k": s.hf, "stride": s.stride,
-                            "bias": s.bias, "act": s.activation})
-            else:
-                got.append({"kind": kind})
+        got = [describe(s) for s in spec.stages]
         if got != blk["stages"] or spec.residual_active(c) != blk["residual"]:
             raise ValueError(f"program block {i} is {got} "
                              f"(residual {spec.residual_active(c)}); the "

@@ -51,6 +51,30 @@ def test_v2_inverted_residual_hand_count():
     assert bd.blocks[2]["residual"] and not bd.blocks[1]["residual"]
 
 
+def test_se_block_hand_count():
+    # MnasNet-A1's first SE block: 56x56x24 -> PW 24->72 -> DW 5x5 s2 ->
+    # SE (72 -> 6 -> 72) -> PW 72->40, no conv bias, both SE FCs biased
+    stages = [
+        {"kind": "PW", "c_out": 72, "bias": False, "act": "relu"},
+        {"kind": "DW", "k": 5, "stride": 2, "bias": False, "act": "relu"},
+        {"kind": "SE", "reduce": 6, "hidden_act": "relu", "act": None},
+        {"kind": "PW", "c_out": 40, "bias": False, "act": None},
+    ]
+    h, w, c = 56, 56, 24
+    macs = weights = 0
+    for st in stages:
+        wk = body.load_module(os.path.join(body.HERE, "work",
+                                           f"{st['kind']}.py"))
+        macs += wk.macs(st, h, w, c)
+        weights += wk.n_weights(st, c)
+        h, w, c = wk.out_shape(st, h, w, c)
+    assert (h, w, c) == (28, 28, 40)
+    se_macs = 2 * 72 * 6 + 28 * 28 * 72 + 28 * 28 * 72  # FCs, pool, scale
+    assert macs == (56 * 56 * 24 * 72 + 28 * 28 * 72 * 25 + se_macs
+                    + 28 * 28 * 72 * 40)
+    assert weights == 24 * 72 + 25 * 72 + (2 * 72 * 6 + 6 + 72) + 72 * 40
+
+
 @pytest.mark.parametrize("name,n_blocks,weights,out", [
     (V1, 13, 3.18e6, (7, 7, 1024)),
     (V2, 17, 1.78e6, (7, 7, 320)),
