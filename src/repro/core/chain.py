@@ -193,12 +193,14 @@ def separable_block_spec(c_out: int, *, stride: int = 1,
 
 
 def inverted_residual_spec(c_in: int, c_out: int, *, expand: int = 6,
-                           stride: int = 1, hf: int = 3) -> SeparableSpec:
-    """MobileNetV2 inverted residual: bias-free PW-expand (relu6) -> DW
-    (relu6) -> linear PW-project, residual when shapes allow."""
+                           stride: int = 1, hf: int = 3,
+                           activation: str = "relu6") -> SeparableSpec:
+    """MobileNetV2 inverted residual: bias-free PW-expand -> DW, both with
+    ``activation`` (MobileNetV2's relu6 by default) -> linear PW-project,
+    residual when shapes allow."""
     return SeparableSpec(stages=(
-        PW(c_in * expand, activation="relu6"),
-        DW(stride=stride, activation="relu6", hf=hf, wf=hf),
+        PW(c_in * expand, activation=activation),
+        DW(stride=stride, activation=activation, hf=hf, wf=hf),
         PW(c_out),
     ), residual="auto")
 

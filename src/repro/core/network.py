@@ -108,8 +108,11 @@ MOBILENET_V2_BODY: Tuple[Tuple[int, int, int, int], ...] = (
 )
 
 #: MnasNet-A1 body after the 32-channel stem: (t, c, n, s, k, se) rows
-#: (Tan et al. 2019, Fig. 7 — expansion, channels, repeats, stride,
-#: DW kernel, squeeze-excite).  The t=1 first row is the SepConv block.
+#: (Tan et al. 2019, Fig. 7(a) — expansion, channels, repeats, stride,
+#: DW kernel, squeeze-excite; the same rows as the ``mnasnet_a1`` block
+#: strings of the TensorFlow TPU reference, ``mnasnet_models.py``).  The
+#: t=1 first row is the SepConv block.  Every activation is ReLU, the SE
+#: gate a sigmoid, the projections linear.
 MNASNET_A1_BODY: Tuple[Tuple[int, int, int, int, int, bool], ...] = (
     (1, 16, 1, 1, 3, False), (6, 24, 2, 2, 3, False),
     (3, 40, 3, 2, 5, True), (6, 80, 4, 2, 3, False),
@@ -167,9 +170,11 @@ def mobilenet_v2_spec(width_mult: float = 1.0) -> NetworkSpec:
 def mnasnet_a1_spec(width_mult: float = 1.0) -> NetworkSpec:
     """The MnasNet-A1 body: SepConv + MBConv blocks, three stages carrying
     squeeze-excite (SE reduced width = 1/4 of the BLOCK INPUT, the MnasNet
-    convention).  The SE rows declare 4-stage (PW, DW, SE, PW) chains —
-    the planner's ``dw_se`` window fuses the gate onto the DW pass when
-    the full-channel working set fits VMEM (DESIGN.md §10)."""
+    convention).  ReLU throughout, as published: the SepConv DW, every
+    expansion and DW, and the SE hidden layer; the projections are
+    linear.  The SE rows declare 4-stage (PW, DW, SE, PW) chains — the
+    planner's ``dw_se`` window fuses the gate onto the DW pass when the
+    full-channel working set fits VMEM (DESIGN.md §10)."""
     c = make_divisible(32 * width_mult)
     c_in = c
     blocks = []
@@ -187,7 +192,8 @@ def mnasnet_a1_spec(width_mult: float = 1.0) -> NetworkSpec:
                     c, co, expand=t, stride=stride, hf=k))
             else:
                 blocks.append(chain.inverted_residual_spec(
-                    c, co, expand=t, stride=stride, hf=k))
+                    c, co, expand=t, stride=stride, hf=k,
+                    activation="relu"))
             c = co
     return NetworkSpec(name=f"mnasnet_a1_{width_mult:g}",
                        c_in=c_in, blocks=tuple(blocks))

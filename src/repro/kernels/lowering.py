@@ -12,9 +12,14 @@ executables:
 * SAME padding of a fused segment: a one-slab plan hands the kernel the
   unpadded input and its ``pads`` (the kernel makes the halo in VMEM); a
   slabbed plan pads the input in HBM first (``ops.pad_same``), as the
-  ``fusedmb`` and ``dw_se`` segments still do.  The always-on telemetry
+  ``fusedmb`` and ``dw_se`` segments always do.  The always-on telemetry
   counters ``lowering.halo_in_kernel`` / ``lowering.halo_padded`` count
-  the two fused outcomes at trace time;
+  the two outcomes at trace time, the second for every SAME halo padded
+  in HBM ahead of a fused kernel, whatever the kernel;
+* a chain's residual rides in its last kernel pass or is added after it
+  as a separate op; ``lowering.residual_in_kernel`` /
+  ``lowering.residual_separate`` count the two at trace time, one per
+  chain with a residual;
 * ``pw`` / ``dw`` segments -> the standalone ``ops.pwconv`` /
   ``ops.dwconv2d`` kernels;
 * on the XLA backend every fused segment runs ``ref.separable_fused_ref``
@@ -140,6 +145,7 @@ def _run_fused_mb(seg, stages, params, y, res, *, impl, interpret,
         )
         return out.astype(out_dtype)
     if mb.padding.lower() == "same":
+        telemetry.count("lowering.halo_padded")
         y = ops.pad_same(y, mb.hf, mb.wf, mb.stride)
     elif mb.padding.lower() != "valid":
         raise ValueError(mb.padding)
@@ -172,6 +178,7 @@ def _run_dw_se(seg, stages, params, y, *, impl, interpret, stream_dtype,
         )
         return out.astype(out_dtype)
     if d.padding.lower() == "same":
+        telemetry.count("lowering.halo_padded")
         y = ops.pad_same(y, d.hf, d.wf, d.stride)
     elif d.padding.lower() != "valid":
         raise ValueError(d.padding)
@@ -230,6 +237,9 @@ def lower(spec, chain_plan: ChainPlan,
         # the residual add after an unfused tail is a separate op, so the
         # LAST kernel must still store at the stream width in that case
         sep_res = chain_plan.residual and not chain_plan.residual_fused
+        if chain_plan.residual:
+            telemetry.count("lowering.residual_separate" if sep_res
+                            else "lowering.residual_in_kernel")
         for si, seg in enumerate(segments):
             last = si == len(segments) - 1
             k_out = odt if (last and not sep_res) else sdt

@@ -20,7 +20,7 @@ from repro.analysis import jaxpr_audit, planlint
 from repro.analysis.diagnostics import ERROR
 from repro.core import chain, network
 from repro.kernels import blocking, ref
-from repro.kernels.policy import KernelPolicy
+from repro.kernels.policy import DtypePolicy, KernelPolicy
 
 RNG = np.random.default_rng(23)
 PAL = KernelPolicy(impl="pallas", interpret=True)
@@ -276,14 +276,89 @@ def test_efficientnet_lite0_plan_golden():
     assert all(len(p.segments) == 1 for p in nplan.plans)
 
 
-@pytest.mark.parametrize("make", [network.mnasnet_a1_spec,
+def _inverted_residual_relu6(c_in, c_out, *, expand, stride, hf=3):
+    """``chain.inverted_residual_spec`` as it read before it took the
+    activation: relu6 hard-coded."""
+    return chain.SeparableSpec(stages=(
+        chain.PW(c_in * expand, activation="relu6"),
+        chain.DW(stride=stride, activation="relu6", hf=hf, wf=hf),
+        chain.PW(c_out),
+    ), residual="auto")
+
+
+@pytest.mark.parametrize("make", [network.mobilenet_v2_spec,
                                   network.efficientnet_lite0_spec])
-def test_execute_network_new_archs(make):
-    """Both new bodies run end to end through the network engine and match
-    the per-block execute composition."""
+def test_relu6_bodies_unchanged_by_the_activation_argument(make):
+    """MobileNetV2 and EfficientNet-Lite0 keep relu6: every one of their
+    inverted residuals equals the block built before
+    ``inverted_residual_spec`` took the activation, so the specs (the memo
+    and plan keys) are the same objects as before."""
     net = make()
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 16, net.c_in))
+    c, n = net.c_in, 0
+    for b in net.blocks:
+        pw, dw = b.stages[0], b.stages[1]
+        if len(b.stages) == 3 and isinstance(dw, chain.DW):
+            old = _inverted_residual_relu6(
+                c, b.out_channels(c), expand=pw.features // c,
+                stride=dw.stride, hf=dw.hf)
+            assert b == old and hash(b) == hash(old)
+            n += 1
+        c = b.out_channels(c)
+    assert n == {network.mobilenet_v2_spec: 16,
+                 network.efficientnet_lite0_spec: 11}[make]
+
+
+def test_mnasnet_a1_spec_is_relu_as_published():
+    """ReLU in every activated stage (SepConv DW, every expansion and DW,
+    the SE hidden layer), linear projections, SE reduced to a quarter of
+    the block input, 5x5 taps in the (3, 40) and (6, 160) rows."""
+    net = network.mnasnet_a1_spec(1.0)
+    acts, se, taps = set(), [], []
+    for b in net.blocks:
+        *inner, proj = b.stages
+        assert isinstance(proj, chain.PW) and proj.activation is None
+        for st in inner:
+            acts.add(st.activation)
+            if isinstance(st, chain.SE):
+                se.append(st.reduce)
+            if isinstance(st, chain.DW):
+                taps.append(st.hf)
+    assert acts == {"relu"}
+    assert se == [6, 10, 10, 20, 28, 28, 40, 40]
+    assert taps == [3] * 3 + [5] * 3 + [3] * 6 + [5] * 3 + [3]
+
+
+@pytest.mark.parametrize("make,stream", [
+    pytest.param(network.mnasnet_a1_spec, None, id="mnasnet_a1_spec"),
+    pytest.param(network.efficientnet_lite0_spec, None,
+                 id="efficientnet_lite0_spec"),
+    pytest.param(network.mnasnet_a1_spec, "bfloat16",
+                 id="mnasnet_a1_spec-pallas-bf16"),
+])
+def test_execute_network_new_archs(make, stream):
+    """Both new bodies run end to end through the network engine and match
+    the per-block execute composition.  On the Pallas path with a bf16
+    stream, MnasNet-A1 at a 32x32 body input (every one of its 16 blocks
+    and SE reduce widths as at 224, the plan too: fused2 1, fused3 7, pw
+    16, dw_se 8) matches the fp32 oracle within the bf16 tolerance."""
+    net = make()
     params = network.init_network(jax.random.PRNGKey(0), net)
+    if stream is not None:
+        x = jax.random.uniform(jax.random.PRNGKey(1), (2, 32, 32, net.c_in),
+                               maxval=4.0)
+        pol = KernelPolicy(impl="pallas", interpret=True, on_failure="raise",
+                           dtype_policy=DtypePolicy(stream=stream))
+        assert _hist(network.plan_network(net, x.shape, policy=pol)) == {
+            "fused2": 1, "fused3": 7, "pw": 16, "dw_se": 8}
+        got = np.asarray(network.execute_network(
+            net, network.cast_network_params(params, stream), x,
+            policy=pol), np.float32)
+        want = np.asarray(network.reference_network(net, params, x))
+        assert got.shape == want.shape == (2, 2, 2, 320)
+        rel = np.abs(got - want).max() / np.abs(want).max()
+        assert rel < network.BF16_REL_TOL, rel
+        return
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 16, net.c_in))
     pol = KernelPolicy(impl="xla")
     y = network.execute_network(net, params, x, policy=pol)
     o = x

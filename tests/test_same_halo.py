@@ -184,3 +184,35 @@ def test_bodies_at_224_make_every_halo_in_kernel(body):
     jax.eval_shape(network.build_network_fn(net, nplan, pol), params,
                    jax.ShapeDtypeStruct(shape, jnp.bfloat16))
     assert _counters() == {"lowering.halo_in_kernel": BODIES[body]}
+
+
+#: Per build at a 224 image (body input 112x112, bf16 stream): SAME halos
+#: made in the kernel / padded in HBM, residuals inside the last kernel /
+#: added as a separate op.  ``dw_se`` and ``fusedmb`` segments pad in HBM.
+LOWERING_COUNTS = {
+    "mobilenet_v1_spec": (13, 0, 0, 0),
+    "mobilenet_v2_spec": (17, 0, 10, 0),
+    "mnasnet_a1_spec": (8, 8, 4, 5),
+    "efficientnet_lite0_spec": (12, 4, 9, 0),
+}
+
+
+@pytest.mark.parametrize("body", LOWERING_COUNTS)
+def test_lowering_counters_per_build_at_224(body):
+    net = getattr(network, body)(1.0)
+    pol = KernelPolicy(impl="pallas", interpret=True, on_failure="raise",
+                       dtype_policy=DtypePolicy(stream="bfloat16"))
+    shape = (128, 112, 112, net.c_in)
+    nplan = network.plan_network(net, shape, dtype=jnp.bfloat16, policy=pol)
+    params = [[{k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16)
+                for k, v in p.items()}
+               for p in param_structs(spec, bshape[-1], jnp.bfloat16)]
+              for spec, bshape in zip(net.blocks, nplan.block_shapes)]
+    telemetry.reset_runtime_telemetry()
+    jax.eval_shape(network.build_network_fn(net, nplan, pol), params,
+                   jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    counters = telemetry.runtime_report()["counters"]
+    names = ("lowering.halo_in_kernel", "lowering.halo_padded",
+             "lowering.residual_in_kernel", "lowering.residual_separate")
+    assert tuple(counters.get(n, 0) for n in names) == LOWERING_COUNTS[body]
+    assert {n for n in counters if n.startswith("lowering.")} <= set(names)

@@ -167,3 +167,28 @@ def test_body_names_its_blocks_and_kernels_for_v5e(one_chip):
                          ("fused3", ["b01", "fused3"]),
                          ("fused3", ["b02", "fused3"])]
     assert all(op.endswith("/pallas_call") for _, op in kernels)
+
+
+def test_mnasnet_body_compiles_for_v5e(one_chip):
+    """The MnasNet-A1 body at a 224 image, bf16 stream, batch 1, as one
+    program: one ``tpu_custom_call`` per planned Pallas pass (32), and a
+    SAME pad in HBM ahead of each of the 8 ``dw_se`` kernels and of no
+    other — what ``lowering.halo_padded`` counts."""
+    net = network.mnasnet_a1_spec(1.0)
+    pol = KernelPolicy(impl="pallas", on_failure="raise",
+                       dtype_policy=DtypePolicy(stream="bfloat16"))
+    shape = (1, RES, RES, net.c_in)
+    nplan = network.plan_network(net, shape, dtype=jnp.bfloat16, policy=pol)
+    params = [[{k: _sds(v.shape, jnp.bfloat16, one_chip)
+                for k, v in p.items()}
+               for p in param_structs(spec, bshape[-1], jnp.bfloat16)]
+              for spec, bshape in zip(net.blocks, nplan.block_shapes)]
+    text = jax.jit(network.build_network_fn(net, nplan, pol)).lower(
+        params, _sds(shape, jnp.bfloat16, one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        nplan.n_pallas_calls) == 32
+    same_pads = sorted({re.search(r"/(b\d\d)/dw_se/same_pad/", n).group(1)
+                        for n in re.findall(r'op_name="([^"]*)"', text)
+                        if "/same_pad/" in n})
+    assert same_pads == ["b03", "b04", "b05", "b10", "b11", "b12", "b13",
+                         "b14"]
