@@ -76,7 +76,9 @@ def analyze_network(net, nplan, *,
                     block_dtype_policies=None, jaxpr: bool = True,
                     ) -> Report:
     """All passes over a resolved NetworkPlan: each block analyzed at the
-    shape/dtype the plan walk recorded, under its effective policy."""
+    shape/dtype the plan walk recorded, under its effective policy, and
+    the batch-minor kernel block 0 takes where the body input may arrive
+    so (``planlint.body_input_model``)."""
     from repro.core.network import resolve_block_policies
     policies = resolve_block_policies(net, policy, block_dtype_policies)
     report = Report()
@@ -86,6 +88,13 @@ def analyze_network(net, nplan, *,
         report.extend(analyze_chain(
             spec, cp, shape, dtype=jnp.dtype(dt), policy=pol,
             label=f"block{i}", jaxpr=jaxpr).diagnostics)
+    model = planlint.body_input_model(net.blocks[0], nplan.plans[0],
+                                      nplan.block_shapes[0])
+    if model is not None:
+        segment = "block0/seg0/fused2.batch_minor"
+        report.extend(planlint.lint_body_input(
+            model, nplan.plans[0].vmem_budget, segment=segment))
+        report.extend(mosaic_check.lint_model(model, segment))
     return report
 
 

@@ -358,8 +358,7 @@ def check_grid(model: KernelModel, *, segment: str = "",
             "PL121", INFO,
             f"grid {model.grid} too large for exhaustive coverage check; "
             "bounds checked at boundary samples only", segment, geometry))
-    red_dims = [i for i, s in enumerate(model.dimension_semantics)
-                if s == "arbitrary"]
+    red_dims = model.reductions
 
     out = model.output
     out_blocks = tuple(-(-a // blk) for a, blk
@@ -463,6 +462,27 @@ def chain_models(spec, chain_plan: ChainPlan, x_shape: Sequence[int],
             model = None
         out.append((label, geom, model))
     return out
+
+
+def body_input_model(spec, chain_plan: ChainPlan, x_shape: Sequence[int],
+                     ) -> Optional[KernelModel]:
+    """The KernelModel of the batch-minor kernel the body's first chain
+    lowers to when its input arrives batch-minor
+    (``lowering.input_in_place``); None when it cannot."""
+    from repro.kernels import lowering  # lazy: lowering imports the runtime
+    if not lowering.input_in_place(spec, chain_plan, x_shape,
+                                   lowering.BATCH_MINOR, "pallas"):
+        return None
+    return lowering.batch_minor_model(spec, chain_plan, x_shape)
+
+
+def lint_body_input(model: KernelModel, budget: int, *,
+                    segment: str) -> List[Diagnostic]:
+    """Derived VMEM (PL103) and grid proofs (PL120-PL123) of the
+    :func:`body_input_model`."""
+    geometry = f"{model.name} grid={model.grid}"
+    return (check_vmem_derived(model, budget, segment, geometry)
+            + check_grid(model, segment=segment, geometry=geometry))
 
 
 def lint_chain(spec, chain_plan: ChainPlan, x_shape: Sequence[int], *,

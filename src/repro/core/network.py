@@ -563,13 +563,43 @@ def tune_network(net: NetworkSpec, params, x, *,
 # execute_network: the whole backbone as ONE jitted call
 # ---------------------------------------------------------------------------
 
+def device_layout(shape, dtype) -> Optional[Tuple[int, ...]]:
+    """Major-to-minor order of the default device's layout for an array of
+    ``shape`` and ``dtype``: the layout a jitted call's argument has unless
+    its caller says otherwise.  On the TPU at a batch of 128 it is
+    batch-minor (DESIGN.md §3).  None where the backend does not say."""
+    from jax.experimental.layout import Layout
+    dev = jax.devices()[0]
+    try:
+        pj = dev.client.get_default_layout(jnp.dtype(dtype), tuple(shape),
+                                           dev)
+    except jax.errors.JaxRuntimeError:  # a backend without layouts
+        return None
+    return tuple(Layout.from_pjrt_layout(pj).major_to_minor)
+
+
+def array_layout(x) -> Optional[Tuple[int, ...]]:
+    """Major-to-minor order of ``x``'s own layout; None for an array (or
+    a tracer) that does not expose one."""
+    try:
+        return tuple(x.format.layout.major_to_minor)
+    except AttributeError:
+        return None
+
+
 def build_network_fn(net: NetworkSpec, nplan: NetworkPlan,
                      policy: KernelPolicy = DEFAULT_POLICY,
-                     block_dtype_policies=None):
+                     block_dtype_policies=None, input_layout="device"):
     """Compose the per-block lowered runners into one ``run(params, x)``.
     Pure composition — every block executes its planned blocks verbatim
     (the lowering never re-plans), so jitting ``run`` compiles the whole
     backbone as one program.
+
+    ``input_layout`` is the major-to-minor order the body input arrives
+    in: ``"device"`` takes :func:`device_layout` for block 0's shape and
+    dtype, None means unknown.  Where ``lowering.input_in_place`` admits
+    it, block 0 reads the batch-minor input as it lies (DESIGN.md §3);
+    decided here, once per build.
 
     Quarantine honoring (DESIGN.md §9): the planner already degrades
     banned FUSION rungs at plan time, but an ``"unfused"`` ban (the Pallas
@@ -588,11 +618,19 @@ def build_network_fn(net: NetworkSpec, nplan: NetworkPlan,
             for spec, pol, shape, dt in zip(net.blocks, policies,
                                             nplan.block_shapes,
                                             nplan.block_dtypes))
-    runners = [lowering.lower(spec, cp, pol)
-               for spec, cp, pol in zip(net.blocks, nplan.plans, policies)]
+    if input_layout == "device":
+        input_layout = device_layout(nplan.block_shapes[0],
+                                     nplan.block_dtypes[0])
+    in_place = lowering.input_in_place(
+        net.blocks[0], nplan.plans[0], nplan.block_shapes[0], input_layout,
+        policies[0].resolved())
+    runners = [lowering.lower(spec, cp, pol, batch_minor=in_place and i == 0)
+               for i, (spec, cp, pol) in enumerate(zip(
+                   net.blocks, nplan.plans, policies))]
 
     def run(params, x):
         assert len(params) == len(runners), (len(params), len(runners))
+        x = lowering.body_input(x, in_place)
         for i, (r, p) in enumerate(zip(runners, params)):
             # compile-time only: names the block in every op's metadata
             with jax.named_scope(f"b{i:02d}"):
@@ -676,7 +714,8 @@ def _execute_network_raw(net: NetworkSpec, params, x, *,
                     net, x.shape, dtype=x.dtype, policy=policy,
                     block_dtype_policies=block_dtype_policies)
         fn = jax.jit(build_network_fn(net, nplan, policy,
-                                      block_dtype_policies))
+                                      block_dtype_policies,
+                                      array_layout(x)))
         y = fn(params, x)
     _NETWORK_CACHE[cache_key] = (nplan, fn)
     telemetry.record_build(time.perf_counter_ns() - t0)

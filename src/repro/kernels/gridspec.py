@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -80,6 +80,10 @@ class KernelModel:
     the persistent in-kernel fp32 values the planner budgets (the DW or
     conv intermediate) that are neither operands nor scratch.  ``reshapes`` records in-kernel reshape shapes for
     the Mosaic sublane-collapse lint (``analysis/mosaic_check.py``).
+    ``reduction_dims`` are the "arbitrary" dims that reduce into a
+    revisited output block; None means every arbitrary dim.  An arbitrary
+    dim left out of it carries VMEM state from one step to the next and
+    writes its own output block at each.
     """
     name: str
     grid: Tuple[int, ...]
@@ -89,6 +93,14 @@ class KernelModel:
     scratch_bytes: int = 0
     value_bytes: int = 0
     reshapes: Tuple[Tuple[Tuple[int, ...], Tuple[int, ...]], ...] = ()
+    reduction_dims: Optional[Tuple[int, ...]] = None
+
+    @property
+    def reductions(self) -> Tuple[int, ...]:
+        if self.reduction_dims is not None:
+            return self.reduction_dims
+        return tuple(i for i, s in enumerate(self.dimension_semantics)
+                     if s == "arbitrary")
 
     @property
     def grid_points(self) -> int:
@@ -112,8 +124,11 @@ def in_specs_from_model(model: KernelModel) -> list:
             # an Element, the batch dim included
             shape = tuple(pl.Element(d) for d in br.block_shape)
             specs.append(pl.BlockSpec(shape, br.index_map))
-        else:
+        elif br.streamed:
             specs.append(pl.BlockSpec(br.block_shape, br.index_map))
+        else:
+            specs.append(pl.BlockSpec(br.block_shape, br.index_map,
+                                      pipeline_mode=pl.Buffered(1)))
     return specs
 
 

@@ -16,6 +16,14 @@ executables:
   counters ``lowering.halo_in_kernel`` / ``lowering.halo_padded`` count
   the two outcomes at trace time, the second for every SAME halo padded
   in HBM ahead of a fused kernel, whatever the kernel;
+* the body input (``build_network_fn``'s block 0): when it arrives
+  batch-minor — XLA's default TPU layout at a batch of 128, see
+  :func:`input_in_place` — a one-slab stride-1 ``fused2`` first segment
+  reads it as it lies, through ``separable_fused_batch_minor``, with no
+  relayout ``copy`` ahead of the kernel; otherwise the first kernel takes
+  the channel-minor array and XLA relays out whatever arrives.
+  ``lowering.input_in_place`` / ``lowering.input_relayout`` count the two
+  at trace time, once per build;
 * a chain's residual rides in its last kernel pass or is added after it
   as a separate op; ``lowering.residual_in_kernel`` /
   ``lowering.residual_separate`` count the two at trace time, one per
@@ -54,7 +62,10 @@ from repro.kernels.epilogue import apply_epilogue
 from repro.kernels.fused_mbconv import fused_mbconv_pallas
 from repro.kernels.policy import DEFAULT_POLICY, KernelPolicy
 from repro.kernels.se_epilogue import dw_se_pallas
-from repro.kernels.separable_fused import separable_fused_pallas
+from repro.kernels.separable_fused import (BATCH_LANES,
+                                           batch_minor_kernel_model,
+                                           separable_fused_batch_minor,
+                                           separable_fused_pallas)
 from repro.runtime import failures, faultinject, telemetry
 
 #: Per-stage parameter leaves the lowering consumes: PW stages take
@@ -78,8 +89,63 @@ _INJECT = {"fused3": "lowering:separable_fused",
            "dw": "lowering:dwconv2d"}
 
 
+#: ``x.format.layout.major_to_minor`` of a (B, H, W, C) array laid out
+#: batch-minor: H major, then W, then C, the batch in the lanes.
+BATCH_MINOR = (1, 2, 3, 0)
+
+
 def _cast(a, dtype):
     return None if a is None else a.astype(dtype)
+
+
+def input_in_place(spec, chain_plan: ChainPlan, x_shape, layout,
+                   impl: str) -> bool:
+    """Whether the chain's first kernel reads a body input of ``x_shape``
+    laid out ``layout`` (major to minor; None when unknown) as it lies:
+    the layout is :data:`BATCH_MINOR`, the batch a multiple of 128, the
+    backend Pallas, and the first segment a ``fused2`` of one row slab
+    whose stride-1 SAME halo the kernel makes, with no residual (which
+    would need the channel-minor input).  The width must fill whole
+    sublane tiles, for the kernel's image-major store, and the kernel's
+    working set the plan's VMEM budget."""
+    if layout is None or tuple(layout) != BATCH_MINOR or impl != "pallas":
+        return False
+    b, h, w, _ = x_shape
+    seg = chain_plan.segments[0]
+    if (b % BATCH_LANES or w % 8 or seg.kind != "fused2"
+            or chain_plan.residual):
+        return False
+    d = spec.stages[seg.stages[0]]
+    pads = d.same_pads(h, w)
+    if (d.stride != 1 or pads is None or h < d.hf or blocking.kernel_pads(
+            pads, d.out_dims(h, w)[0], seg.plan.slab_h) is None):
+        return False
+    return batch_minor_model(spec, chain_plan, x_shape).vmem_bytes() <= (
+        chain_plan.vmem_budget)
+
+
+def batch_minor_model(spec, chain_plan: ChainPlan, x_shape):
+    """The kernel model of the batch-minor first segment (biases counted
+    whether or not the spec has them, as the planner does)."""
+    seg = chain_plan.segments[0]
+    d, proj = (spec.stages[i] for i in seg.stages)
+    b, h, w, c = (int(v) for v in x_shape)
+    nb = seg.plan.dtype_bytes
+    return batch_minor_kernel_model(
+        b=b, h=h, w=w, c=c, co=proj.features, hf=d.hf, wf=d.wf,
+        pads=d.same_pads(h, w), itemsize=nb, out_itemsize=nb,
+        has_dw_bias=True, has_pw_bias=True)
+
+
+def body_input(x, in_place: bool):
+    """The body input as block 0 takes it, counted once per build: the
+    (H, W, C, B) view of a batch-minor array — a bitcast on the TPU — or
+    the array itself."""
+    if in_place:
+        telemetry.count("lowering.input_in_place")
+        return jnp.transpose(x, BATCH_MINOR)
+    telemetry.count("lowering.input_relayout")
+    return x
 
 
 def _run_fused(seg, stages, params, y, res, *, impl, interpret,
@@ -125,6 +191,23 @@ def _run_fused(seg, stages, params, y, res, *, impl, interpret,
         slab_h=seg.plan.slab_h, interpret=interpret,
         out_dtype=jnp.dtype(out_dtype).name, pads=halo,
     )
+
+
+def _run_fused_batch_minor(seg, stages, params, y, *, interpret,
+                           stream_dtype, out_dtype):
+    """A ``fused2`` first segment over the (H, W, C, B) body input."""
+    i_dw, i_pw = seg.stages
+    d = stages[i_dw]
+    h, w = y.shape[:2]
+    telemetry.count("lowering.halo_in_kernel")
+    return separable_fused_batch_minor(
+        y, params[i_dw]["f"].astype(stream_dtype),
+        params[i_pw]["w"].astype(stream_dtype),
+        _cast(params[i_dw].get("b"), stream_dtype),
+        _cast(params[i_pw].get("b"), stream_dtype),
+        pads=d.same_pads(h, w), dw_activation=d.activation,
+        activation=stages[i_pw].activation, interpret=interpret,
+        out_dtype=jnp.dtype(out_dtype).name)
 
 
 def _run_fused_mb(seg, stages, params, y, res, *, impl, interpret,
@@ -213,7 +296,8 @@ def _run_se(seg, stages, params, y, policy, *, impl, interpret,
 
 
 def lower(spec, chain_plan: ChainPlan,
-          policy: KernelPolicy = DEFAULT_POLICY,
+          policy: KernelPolicy = DEFAULT_POLICY, *,
+          batch_minor: bool = False,
           ) -> Callable[[Sequence[dict], jax.Array], jax.Array]:
     """Map a planned chain onto kernels; returns ``run(params, x)``.
 
@@ -221,6 +305,9 @@ def lower(spec, chain_plan: ChainPlan,
     ``spec.stages`` (see :data:`PARAM_KEYS`).  The residual source is the
     chain input ``x``; it rides inside the final fused kernel pass when
     ``chain_plan.residual_fused``, else it is added as a separate op.
+    With ``batch_minor`` (a chain that :func:`input_in_place` admits)
+    ``x`` is the (H, W, C, B) view :func:`body_input` gives, and the first
+    segment reads it through the batch-minor kernel.
     """
     impl = policy.resolved()
     interpret = policy.interpret
@@ -248,7 +335,11 @@ def lower(spec, chain_plan: ChainPlan,
                 faultinject.check(_INJECT[seg.kind])
                 # compile-time only: names the segment in op metadata
                 with jax.named_scope(seg.kind):
-                    if seg.kind in ("fused3", "fused2"):
+                    if batch_minor and si == 0:
+                        y = _run_fused_batch_minor(
+                            seg, stages, params, y, interpret=interpret,
+                            stream_dtype=sdt, out_dtype=k_out)
+                    elif seg.kind in ("fused3", "fused2"):
                         y = _run_fused(seg, stages, params, y, seg_res,
                                        impl=impl, interpret=interpret,
                                        stream_dtype=sdt, out_dtype=k_out)

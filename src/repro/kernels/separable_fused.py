@@ -73,6 +73,13 @@ enumeration); when even the minimal plan exceeds the budget the planner
 returns None and callers fall back to the unfused composition
 (``ops.separable_fused``).
 
+Batch-minor entry (``separable_fused_batch_minor``, DESIGN.md §3): the
+network's first block reads a (B, H, W, C) body input laid out with the
+batch in the lanes — the TPU's default layout at a batch of 128 — as its
+(H, W, C, B) view, so no relayout copy of the input precedes the kernel.
+The DW taps run over 128 images a vreg; the turn to channel-minor is one
+MXU contraction and one strided store per output pixel, in VMEM.
+
 TPU note: the overlapping input windows use element-offset indexing
 (``pl.Element`` on every block dim, ``gridspec.in_specs_from_model``); the
 row offset is un-tiled and free, the lane offset must be provably
@@ -472,3 +479,256 @@ def separable_fused_pallas(
         name="fused3" if expand_w is not None else "fused2",
     )(*inputs)
     return out[:, :ho, :, :co]
+
+
+# ---------------------------------------------------------------------------
+# Batch-minor entry: the body input as it arrives on the TPU at batch 128
+# ---------------------------------------------------------------------------
+
+#: Images a grid cell of the batch-minor entry computes: one vreg of lanes.
+BATCH_LANES = 128
+
+#: Output columns the batch-minor kernel turns to channel-minor at a time:
+#: half a 112-wide row, so its fp32 turn scratch fits the VMEM budget.
+TURN_COLS = 56
+
+#: Images whose turned rows one step of the store loop writes out.
+_IMAGES_PER_STEP = 8
+
+
+def _turn_cols(w: int) -> int:
+    return TURN_COLS if w % TURN_COLS == 0 else w
+
+
+def _turn_stride(tc: int) -> int:
+    """Rows between two images in the turn scratch: odd, so that the
+    strided store of one pixel's 8 images is one vreg store (an even
+    stride takes 2-8 stores a vreg on v5e)."""
+    return tc | 1
+
+
+def _pixels_per_step(w: int) -> int:
+    return max(p for p in (8, 7, 6, 5, 4, 3, 2, 1) if w % p == 0)
+
+
+def batch_minor_kernel_model(*, b: int, h: int, w: int, c: int, co: int,
+                             hf: int, wf: int, pads, itemsize: int,
+                             out_itemsize: int, has_dw_bias: bool,
+                             has_pw_bias: bool) -> KernelModel:
+    """The grid/BlockSpec geometry of :func:`separable_fused_batch_minor`
+    (DESIGN.md §3, §8): a stride-1 SAME DW -> PW over the body input laid
+    out ``(H, W, C, B)``, batch in the lanes.
+
+    Grid ``(B/128, H)``: each cell writes output row ``t`` of 128 images.
+    The second dim is sequential and carries a ring of the last ``hf``
+    input rows in VMEM, with the zero column halo: cell ``t`` brings in
+    row ``t + bottom`` only, so every input row crosses HBM once.  The
+    first ``bottom`` rows come in with the batch chunk (``head``, one
+    buffer)."""
+    (top, bottom), (left, right) = pads
+    assert hf == top + bottom + 1 and wf == left + right + 1, (hf, wf, pads)
+    lanes = BATCH_LANES
+    assert b % lanes == 0, b
+    last = h - 1
+    inputs = [BlockRef(
+        "x", (h, w, c, b), (1, w, c, lanes),
+        lambda i, t: (jnp.minimum(t + bottom, last), 0, 0, i), itemsize)]
+    if bottom:
+        inputs.append(BlockRef("head", (h, w, c, b), (bottom, w, c, lanes),
+                               lambda i, t: (0, 0, 0, i), itemsize,
+                               streamed=False))
+    inputs.append(BlockRef("dw_f", (hf, wf, c, lanes), (hf, wf, c, lanes),
+                           lambda i, t: (0, 0, 0, 0), itemsize))
+    if has_dw_bias:
+        inputs.append(BlockRef("dw_bias", (c, lanes), (c, lanes),
+                               lambda i, t: (0, 0), itemsize))
+    inputs.append(BlockRef("pw_w", (c, co), (c, co),
+                           lambda i, t: (0, 0), itemsize))
+    if has_pw_bias:
+        inputs.append(BlockRef("pw_bias", (1, co), (1, co),
+                               lambda i, t: (0, 0), itemsize))
+    out_ref = BlockRef("out", (b, h, w, co), (lanes, 1, w, co),
+                       lambda i, t: (i, t, 0, 0), out_itemsize)
+    tc = _turn_cols(w)
+    return KernelModel(
+        name="separable_fused2_batch_minor",
+        grid=(b // lanes, h),
+        dimension_semantics=("parallel", "arbitrary"),
+        inputs=tuple(inputs),
+        output=out_ref,
+        scratch_bytes=(hf * (w + left + right) * c * lanes * itemsize  # ring
+                       + lanes * _turn_stride(tc) * co * 4),         # turn
+        value_bytes=_pixels_per_step(tc) * c * lanes * 4,   # DW pixels
+        reduction_dims=(),
+    )
+
+
+def _batch_minor_kernel(*refs, hf: int, pads, h: int, dw_activation,
+                        activation, has_dwb: bool, has_pwb: bool,
+                        out_dtype, prec):
+    """refs = (x, [head,] f, [dw_bias,] w, [pw_bias,] out, ring, turn).
+
+    x (1, W, C, 128): input row ``t + bottom`` (the last row past the
+    image); head (bottom, W, C, 128): rows ``0 .. bottom-1``; f (Hf, Wf,
+    C, 128) and dw_bias (C, 128): the DW weights broadcast over the lanes;
+    w (C, Co); pw_bias (1, Co); out (128, 1, Wo, Co): output row ``t`` of
+    the batch chunk; ring (Hf, W + left + right, C, 128): the last ``Hf``
+    input rows with the zero column halo, row ``r`` in slot
+    ``(r + top) % Hf``; turn (128 * S, Co) fp32: the PW output of ``Tc``
+    columns after its epilogue, image ``b``'s at rows ``b * S ..`` (S
+    odd, ``_turn_stride``).
+    """
+    (top, bottom), (left, _) = pads
+    it = iter(refs)
+    x_ref = next(it)
+    head_ref = next(it) if bottom else None
+    f_ref = next(it)
+    dwb_ref = next(it) if has_dwb else None
+    w_ref = next(it)
+    pwb_ref = next(it) if has_pwb else None
+    out_ref = next(it)
+    ring_ref = next(it)
+    turn_ref = next(it)
+
+    _, w, c, lanes = x_ref.shape
+    wo, co = out_ref.shape[2], out_ref.shape[3]
+    t = pl.program_id(1)
+    cols = pl.ds(left, w)
+
+    def slot(row):
+        return jax.lax.rem(row + top, hf)
+
+    @pl.when(t == 0)
+    def _start():   # a new batch chunk: the halo, then its first rows
+        ring_ref[...] = jnp.zeros_like(ring_ref)
+        for r in range(bottom):
+            ring_ref[(r + top) % hf, cols] = head_ref[r]
+
+    new = t + bottom
+
+    @pl.when(new < h)
+    def _row():
+        ring_ref[slot(new), cols] = x_ref[0]
+
+    if bottom:
+        @pl.when(new >= h)
+        def _pad_row():
+            ring_ref[slot(new), cols] = jnp.zeros((w, c, lanes),
+                                                  ring_ref.dtype)
+
+    slots = [slot(t - top + n) for n in range(hf)]
+    wf = f_ref.shape[1]
+    pw = w_ref[...].astype(jnp.float32)
+    pwb = pwb_ref[...] if pwb_ref is not None else None
+    tc = _turn_cols(wo)
+    ts = _turn_stride(tc)
+    step = _pixels_per_step(tc)
+
+    def chunk(q, carry):
+        c0 = pl.multiple_of(q * tc, tc)
+
+        def pixels(j, carry):
+            # the DW taps over full 128-image vregs, in fp32
+            w0 = c0 + j * step
+            dw = jnp.zeros((step, c, lanes), jnp.float32)
+            for n in range(hf):
+                for m in range(wf):
+                    dw = dw + (ring_ref[slots[n], pl.ds(w0 + m, step)]
+                               .astype(jnp.float32)
+                               * f_ref[n, m].astype(jnp.float32)[None])
+            dw = _epilogue(
+                dw, dwb_ref[...][None] if dwb_ref is not None else None,
+                dw_activation)
+            # the turn: (C, 128) -> (128, Co) on the MXU, stored image-major
+            for k in range(step):
+                y = jax.lax.dot_general(
+                    dw[k], pw, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=prec)
+                turn_ref[pl.ds(j * step + k, lanes, stride=ts), :] = (
+                    _epilogue(y, pwb, activation))
+            return carry
+
+        jax.lax.fori_loop(0, tc // step, pixels, 0)
+
+        def images(q, carry):
+            for u in range(_IMAGES_PER_STEP):
+                b = q * _IMAGES_PER_STEP + u
+                out_ref[b, 0, pl.ds(c0, tc), :] = turn_ref[
+                    pl.ds(b * ts, tc), :].astype(out_dtype)
+            return carry
+
+        jax.lax.fori_loop(0, lanes // _IMAGES_PER_STEP, images, 0)
+        return carry
+
+    jax.lax.fori_loop(0, wo // tc, chunk, 0)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("pads", "dw_activation", "activation", "interpret",
+                     "out_dtype"))
+def separable_fused_batch_minor(
+    xt: jax.Array,
+    dw_f: jax.Array,
+    pw_w: jax.Array,
+    dw_bias: Optional[jax.Array] = None,
+    pw_bias: Optional[jax.Array] = None,
+    *,
+    pads: blocking.Pads,
+    dw_activation: Optional[str] = "relu6",
+    activation: Optional[str] = None,
+    interpret: bool = False,
+    out_dtype: Optional[str] = None,
+) -> jax.Array:
+    """Fused stride-1 SAME DW -> PW over a batch-minor input: xt (H, W, C,
+    B) with B a multiple of 128; dw_f (Hf, Wf, C); pw_w (C, Co) [+ dw_bias
+    (C,), pw_bias (Co,)] -> (B, H, W, Co), the layout and values of
+    :func:`separable_fused_pallas` on ``xt.transpose(3, 0, 1, 2)``.
+
+    The DW taps run over full 128-image vregs; the turn to channel-minor
+    happens per output pixel in VMEM: the pixel's (C, 128) DW tile,
+    contracted over C with the PW weights on the MXU, gives (128, Co),
+    stored image-major into an fp32 scratch with one strided store a
+    vreg, from which each image's row is stored once (DESIGN.md §3)."""
+    h, w, c, b = xt.shape
+    hf, wf, cf = dw_f.shape
+    cw, co = pw_w.shape
+    assert c == cf == cw, (xt.shape, dw_f.shape, pw_w.shape)
+    odt = jnp.dtype(out_dtype) if out_dtype is not None else xt.dtype
+    lanes = BATCH_LANES
+    model = batch_minor_kernel_model(
+        b=b, h=h, w=w, c=c, co=co, hf=hf, wf=wf, pads=pads,
+        itemsize=xt.dtype.itemsize, out_itemsize=odt.itemsize,
+        has_dw_bias=dw_bias is not None, has_pw_bias=pw_bias is not None)
+    inputs = [xt]
+    if pads[0][1]:
+        inputs.append(xt)
+    inputs.append(jnp.broadcast_to(dw_f[..., None], (hf, wf, c, lanes)))
+    if dw_bias is not None:
+        inputs.append(jnp.broadcast_to(dw_bias[:, None], (c, lanes)))
+    inputs.append(pw_w)
+    if pw_bias is not None:
+        inputs.append(pw_bias.reshape(1, co))
+    for arr, br in zip(inputs, model.inputs):
+        assert arr.shape == br.array_shape, (br.name, arr.shape,
+                                             br.array_shape)
+    kernel = functools.partial(
+        _batch_minor_kernel, hf=hf, pads=pads, h=h,
+        dw_activation=dw_activation, activation=activation,
+        has_dwb=dw_bias is not None, has_pwb=pw_bias is not None,
+        out_dtype=odt, prec=contract_precision(xt.dtype))
+    (left, right) = pads[1]
+    return pl.pallas_call(
+        kernel,
+        grid=model.grid,
+        in_specs=in_specs_from_model(model),
+        out_specs=out_spec_from_model(model),
+        out_shape=jax.ShapeDtypeStruct(model.output.array_shape, odt),
+        scratch_shapes=[
+            pltpu.VMEM((hf, w + left + right, c, lanes), xt.dtype),
+            pltpu.VMEM((lanes * _turn_stride(_turn_cols(w)), co),
+                       jnp.float32)],
+        compiler_params=compiler_params(model),
+        interpret=interpret,
+        name="fused2",
+    )(*inputs)

@@ -206,6 +206,19 @@ def test_pl123_output_depends_on_reduction_dim():
     assert "PL123" in _rules(planlint.check_grid(bad))
 
 
+def test_carry_dim_writes_a_block_per_step():
+    """An "arbitrary" dim left out of ``reduction_dims`` carries state and
+    writes a block per step (the batch-minor kernel's rows): PL123 does
+    not apply to it, and two of its steps writing one block race."""
+    carry = dataclasses.replace(
+        _toy(out_map=lambda i, k: (i, k), out_shape=((32, 16), (8, 8))),
+        reduction_dims=())
+    assert _rules(planlint.check_grid(carry)) == []
+    both = dataclasses.replace(
+        _toy(out_map=lambda i, k: (i, 0)), reduction_dims=())
+    assert _rules(planlint.check_grid(both)) == ["PL122"]
+
+
 def test_grid_sampling_on_huge_grids():
     """Above MAX_GRID_POINTS the check degrades to boundary samples and
     says so (INFO PL121) instead of silently passing."""

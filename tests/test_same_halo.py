@@ -188,12 +188,14 @@ def test_bodies_at_224_make_every_halo_in_kernel(body):
 
 #: Per build at a 224 image (body input 112x112, bf16 stream): SAME halos
 #: made in the kernel / padded in HBM, residuals inside the last kernel /
-#: added as a separate op.  ``dw_se`` and ``fusedmb`` segments pad in HBM.
+#: added as a separate op, the body input read as it lies / through a
+#: relayout.  ``dw_se`` and ``fusedmb`` segments pad in HBM; the CPU
+#: device lays the input out channel-minor.
 LOWERING_COUNTS = {
-    "mobilenet_v1_spec": (13, 0, 0, 0),
-    "mobilenet_v2_spec": (17, 0, 10, 0),
-    "mnasnet_a1_spec": (8, 8, 4, 5),
-    "efficientnet_lite0_spec": (12, 4, 9, 0),
+    "mobilenet_v1_spec": (13, 0, 0, 0, 0, 1),
+    "mobilenet_v2_spec": (17, 0, 10, 0, 0, 1),
+    "mnasnet_a1_spec": (8, 8, 4, 5, 0, 1),
+    "efficientnet_lite0_spec": (12, 4, 9, 0, 0, 1),
 }
 
 
@@ -213,6 +215,7 @@ def test_lowering_counters_per_build_at_224(body):
                    jax.ShapeDtypeStruct(shape, jnp.bfloat16))
     counters = telemetry.runtime_report()["counters"]
     names = ("lowering.halo_in_kernel", "lowering.halo_padded",
-             "lowering.residual_in_kernel", "lowering.residual_separate")
+             "lowering.residual_in_kernel", "lowering.residual_separate",
+             "lowering.input_in_place", "lowering.input_relayout")
     assert tuple(counters.get(n, 0) for n in names) == LOWERING_COUNTS[body]
     assert {n for n in counters if n.startswith("lowering.")} <= set(names)

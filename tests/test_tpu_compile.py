@@ -192,3 +192,39 @@ def test_mnasnet_body_compiles_for_v5e(one_chip):
                         if "/same_pad/" in n})
     assert same_pads == ["b03", "b04", "b05", "b10", "b11", "b12", "b13",
                          "b14"]
+
+
+@pytest.mark.parametrize("body", ["mobilenet_v1_spec", "mobilenet_v2_spec"])
+def test_batch_minor_body_input_is_read_in_place_for_v5e(one_chip, body):
+    """The first two blocks at batch 128, bf16 stream: the v5e lays the
+    body input out batch-minor, and block 0's kernel reads a bitcast of
+    it — no HLO ``copy`` or ``transpose`` takes the entry parameter."""
+    full = getattr(network, body)(1.0)
+    net = dataclasses.replace(full, blocks=full.blocks[:2])
+    pol = KernelPolicy(impl="pallas", on_failure="raise",
+                       dtype_policy=DtypePolicy(stream="bfloat16"))
+    shape = (128, RES, RES, net.c_in)
+    nplan = network.plan_network(net, shape, dtype=jnp.bfloat16, policy=pol)
+    params = [[{k: _sds(v.shape, jnp.bfloat16, one_chip)
+                for k, v in p.items()}
+               for p in param_structs(spec, bshape[-1], jnp.bfloat16)]
+              for spec, bshape in zip(net.blocks, nplan.block_shapes)]
+    fn = network.build_network_fn(net, nplan, pol,
+                                  input_layout=lowering.BATCH_MINOR)
+    text = jax.jit(fn).lower(params, _sds(shape, jnp.bfloat16,
+                                          one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        nplan.n_pallas_calls) == 2
+    (param,) = re.findall(
+        r"(%\S+) = bf16\[128,112,112,32\]\{0,3,2,1:\S* parameter\(", text)
+    users = [line for line in text.splitlines()
+             if re.search(re.escape(param) + r"[,)]", line)
+             and " parameter(" not in line]
+    assert users and all(re.search(r"= \S+ bitcast\(", u) for u in users)
+    views = {re.match(r"\s*(%\S+) =", u).group(1) for u in users}
+    (b00,) = [line for line in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line
+              and "/b00/fused2/" in line]
+    assert any(v + "," in b00 or v + ")" in b00 for v in views)
+    assert not re.findall(r"= \S+ (?:copy|transpose)\(" + re.escape(param),
+                          text)
