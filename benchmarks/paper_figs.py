@@ -27,7 +27,7 @@ from benchmarks.layers import SEP_SUITES, SUITES, sep_geometry
 from repro.core import intensity as it
 from repro.kernels import blocking, ref
 
-# v5e single-chip constants (roofline/analysis.py)
+# v5e single-chip constants: bf16 peak FLOP/s and HBM bytes/s
 PEAK = 197e12
 HBM = 819e9
 # quad-core Cortex-A57 (paper fig. 1): ~32 GFLOP/s fp32 peak, ~25.6 GB/s LPDDR4

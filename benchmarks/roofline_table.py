@@ -1,60 +1,9 @@
-"""Assemble the EXPERIMENTS.md roofline table from dry-run artifacts, plus
-the separable-block fusion accounting table (fused vs unfused HBM bytes,
-with the removed DW-intermediate term broken out — DESIGN.md §3)."""
+"""The separable-block fusion accounting table (fused vs unfused HBM bytes,
+with the removed DW-intermediate term broken out — DESIGN.md §3) and the
+per-block ChainPlan table of whole MobileNetV2 inverted residuals."""
 from __future__ import annotations
 
-import glob
-import json
-import os
-
-from repro.configs.base import SHAPES
-from repro.configs.registry import ARCH_IDS
 from repro.core import intensity as it
-
-COLUMNS = [
-    "arch", "shape", "mesh", "status", "compute_s", "memory_s",
-    "collective_s", "dominant", "useful_flop_ratio", "roofline_mfu_bound",
-]
-
-
-def load_records(art_dir: str = "artifacts/dryrun") -> list[dict]:
-    recs = []
-    for path in sorted(glob.glob(os.path.join(art_dir, "*.json"))):
-        with open(path) as f:
-            recs.append(json.load(f))
-    return recs
-
-
-def _ms(x):
-    return f"{x*1e3:.2f}" if isinstance(x, (int, float)) else "-"
-
-
-def markdown_table(recs: list[dict], mesh: str = "single") -> str:
-    lines = [
-        "| arch | shape | compute (ms) | memory (ms) | collective (ms) | "
-        "dominant | useful-FLOP ratio | MFU bound |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    order = {a: i for i, a in enumerate(ARCH_IDS)}
-    sorder = {s: i for i, s in enumerate(SHAPES)}
-    recs = [r for r in recs if r.get("mesh") == mesh]
-    recs.sort(key=lambda r: (order.get(r["arch"], 99),
-                             sorder.get(r["shape"], 9)))
-    for r in recs:
-        if r.get("status") == "skipped":
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | "
-                         f"skipped (sub-quadratic gate) | — | — |")
-            continue
-        if r.get("status") != "ok":
-            lines.append(f"| {r['arch']} | {r['shape']} | FAILED | | | | | |")
-            continue
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {_ms(r['compute_s'])} | "
-            f"{_ms(r['memory_s'])} | {_ms(r['collective_s'])} | "
-            f"{r['dominant'].replace('_s','')} | "
-            f"{r['useful_flop_ratio']:.2f} | "
-            f"{r['roofline_mfu_bound']*100:.1f}% |")
-    return "\n".join(lines)
 
 
 def separable_fusion_rows(dtype=None) -> list[dict]:
@@ -214,25 +163,7 @@ def separable_fusion_markdown() -> str:
     return "\n".join(lines)
 
 
-def csv_rows(recs: list[dict]) -> list[str]:
-    out = []
-    for r in recs:
-        if r.get("status") != "ok":
-            continue
-        name = f"roofline/{r['arch']}/{r['shape']}/{r['mesh']}"
-        dominant_ms = r[r["dominant"]] * 1e3
-        out.append(f"{name},{dominant_ms*1e3:.1f},"
-                   f"dominant={r['dominant']};"
-                   f"mfu_bound={r['roofline_mfu_bound']*100:.1f}%")
-    return out
-
-
 if __name__ == "__main__":
-    recs = load_records()
-    print(markdown_table(recs, "single"))
-    print()
-    print(markdown_table(recs, "multi"))
-    print()
     print(separable_fusion_markdown())
     print()
     print(chain_fusion_markdown())

@@ -6,8 +6,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
 * fig 7 — modeled core-scalability curves (channel- vs row-parallel).
 * fig 1 anchor — Algorithm-1 naive loops vs compiled (the paper's
   "Unoptimized" point).
-* roofline — dominant-term summary per (arch x shape) from the dry-run
-  artifacts (if present; run ``python -m repro.launch.dryrun --all`` first).
 
 Flags:
 * ``--full``     — benchmark every layer (default: first 3 per suite).
@@ -95,7 +93,6 @@ def main() -> None:
             args.check_baseline or trajectory.DEFAULT_BASELINE))
 
     from benchmarks.paper_figs import run_all
-    from benchmarks.roofline_table import csv_rows, load_records
 
     results = run_all(quick=not args.full, dry_run=args.dry_run)
     rows = []
@@ -175,9 +172,6 @@ def main() -> None:
                                         report_path=args.runtime_report)
         rows.extend(rt_rows)
         results["runtime"] = rt_recs
-
-    recs = load_records()
-    rows.extend(csv_rows(recs))
 
     print("name,us_per_call,derived")
     for row in rows:

@@ -12,12 +12,7 @@ from repro.core.chain import (
     plan,
     separable_block_spec,
 )
-from repro.core.dwconv import (
-    depthwise1d_causal,
-    depthwise1d_step,
-    depthwise2d,
-    init_conv_state,
-)
+from repro.core.dwconv import depthwise2d
 from repro.core.network import (
     NetworkPlan,
     NetworkSpec,
@@ -55,12 +50,9 @@ __all__ = [
     "mobilenet_v2_spec",
     "plan_network",
     "tune_network",
-    "depthwise1d_causal",
-    "depthwise1d_step",
     "depthwise2d",
     "execute",
     "init_chain",
-    "init_conv_state",
     "init_inverted_residual",
     "init_separable",
     "inverted_residual",

@@ -1,19 +1,15 @@
 """DWConv — the paper's depthwise-convolution contribution as a framework op.
 
-Two entry points, matching where depthwise convolution appears in practice:
-
-* :func:`depthwise2d` — NHWC spatial DWConv (MobileNet/MnasNet workloads,
-  conv frontends).
-* :func:`depthwise1d_causal` — causal sequence DWConv (Mamba/Hymba heads,
-  xLSTM conv preactivation) + :func:`depthwise1d_step` for decode.
+:func:`depthwise2d` runs NHWC spatial DWConv (MobileNet/MnasNet workloads) as
+one standalone op under a ``KernelPolicy``; the chain engine fuses the same
+stage into its separable kernels instead.
 """
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.pwconv import DEFAULT_POLICY, KernelPolicy
-from repro.kernels import ops, ref
+from repro.kernels import ops
 
 
 def depthwise2d(
@@ -30,25 +26,3 @@ def depthwise2d(
         impl=policy.impl, interpret=policy.interpret,
     )
 
-
-def depthwise1d_causal(
-    x: jax.Array,
-    f: jax.Array,
-    *,
-    policy: KernelPolicy = DEFAULT_POLICY,
-) -> jax.Array:
-    """x (B, L, D) * f (K, D) -> (B, L, D), causal."""
-    return ops.dwconv1d_causal(
-        x, f, impl=policy.impl, interpret=policy.interpret
-    )
-
-
-def depthwise1d_step(
-    state: jax.Array, x_t: jax.Array, f: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """Single-token decode step; state (B, K-1, D) of past inputs."""
-    return ref.dwconv1d_step_ref(state, x_t, f)
-
-
-def init_conv_state(batch: int, k: int, d: int, dtype=jnp.float32) -> jax.Array:
-    return jnp.zeros((batch, max(k - 1, 1), d), dtype)

@@ -1,9 +1,8 @@
 """PWConv — the paper's pointwise-convolution contribution as a framework op.
 
-Every dense projection in the framework (attention QKV/O, MLP, MoE experts,
-router, unembed) routes through :func:`pointwise`, so the paper's
-output-stationary GEMM is a first-class, globally selectable feature
-(``KernelPolicy``), not a benchmark-only artifact.
+:func:`pointwise` runs the paper's output-stationary GEMM as one standalone
+1x1 conv under a ``KernelPolicy``; the chain engine fuses the same stage into
+its separable kernels instead.
 """
 from __future__ import annotations
 

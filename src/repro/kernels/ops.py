@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import blocking, ref
-from repro.kernels.dwconv1d import dwconv1d_causal_pallas
 from repro.kernels.dwconv2d import dwconv2d_pallas
 from repro.kernels.epilogue import apply_epilogue
 from repro.kernels.policy import resolve_impl
@@ -79,24 +78,6 @@ def dwconv2d(
     return dwconv2d_pallas(x, f, stride=stride, block_c=block_c,
                            vmem_budget=vmem_budget, interpret=interpret,
                            out_dtype=out_dtype)
-
-
-def dwconv1d_causal(
-    x: jax.Array,
-    f: jax.Array,
-    *,
-    impl: str = "auto",
-    interpret: bool = False,
-    block_l: int = 1024,
-    block_d: int = 256,
-) -> jax.Array:
-    """Causal depthwise 1-D conv. x (B,L,D), f (K,D)."""
-    impl = _resolve(impl)
-    if impl == "xla":
-        return ref.dwconv1d_causal_ref(x, f)
-    return dwconv1d_causal_pallas(
-        x, f, block_l=block_l, block_d=block_d, interpret=interpret
-    )
 
 
 def separable_fused(

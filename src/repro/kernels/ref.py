@@ -69,40 +69,6 @@ def dwconv2d_loops_ref(
 
 
 # ---------------------------------------------------------------------------
-# Depthwise causal 1-D convolution (SSM/Mamba conv preactivation).
-# ---------------------------------------------------------------------------
-
-
-def dwconv1d_causal_ref(x: jax.Array, f: jax.Array) -> jax.Array:
-    """Causal depthwise conv. x: (B, L, D); f: (K, D) -> (B, L, D).
-
-    out[b, l, d] = sum_k x[b, l - (K-1) + k, d] * f[k, d]  (zero left-pad).
-    """
-    assert x.ndim == 3 and f.ndim == 2 and x.shape[-1] == f.shape[-1]
-    k = f.shape[0]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    out = jnp.zeros(x.shape, jnp.float32)
-    for i in range(k):  # K is tiny (3..5) and static — unrolled shifts.
-        out = out + xp[:, i : i + x.shape[1], :] * f[i][None, None, :].astype(
-            jnp.float32
-        )
-    return out.astype(x.dtype)
-
-
-def dwconv1d_step_ref(
-    state: jax.Array, x_t: jax.Array, f: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """Single decode step. state: (B, K-1, D) past inputs; x_t: (B, D).
-
-    Returns (new_state, y_t) with y_t = causal conv output at this position.
-    """
-    k = f.shape[0]
-    window = jnp.concatenate([state, x_t[:, None, :]], axis=1)  # (B, K, D)
-    y = jnp.einsum("bkd,kd->bd", window.astype(jnp.float32), f.astype(jnp.float32))
-    return window[:, 1:, :] if k > 1 else state, y.astype(x_t.dtype)
-
-
-# ---------------------------------------------------------------------------
 # Pointwise convolution == GEMM (paper Alg. 3/5/6).
 # ---------------------------------------------------------------------------
 

@@ -2,8 +2,7 @@ import os
 import sys
 import tempfile
 
-# Tests run on the single host device (the 512-device override is ONLY for
-# launch/dryrun.py). Make repo sources importable without install.
+# Make repo sources importable without install.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # The default KernelPolicy runs in degrade mode (DESIGN.md §9), which

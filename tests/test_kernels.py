@@ -6,7 +6,6 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.dwconv1d import dwconv1d_causal_pallas
 from repro.kernels.dwconv2d import dwconv2d_pallas
 from repro.kernels.pwconv import pwconv_pallas
 
@@ -62,42 +61,6 @@ def test_dwconv2d_ref_matches_naive_loops():
     lax_ = ref.dwconv2d_ref(jnp.asarray(x), jnp.asarray(f), stride=2,
                             padding="valid")
     np.testing.assert_allclose(naive, np.asarray(lax_), rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# dwconv1d (causal)
-# ---------------------------------------------------------------------------
-
-DW1D_CASES = [
-    (1, 16, 8, 4, 8, 8),
-    (2, 100, 48, 4, 32, 16),
-    (2, 64, 64, 3, 64, 64),     # single L block
-    (1, 37, 20, 5, 8, 8),       # padding both dims
-]
-
-
-@pytest.mark.parametrize("b,l,d,k,bl,bd", DW1D_CASES)
-def test_dwconv1d_matches_ref(b, l, d, k, bl, bd):
-    x = _arr((b, l, d))
-    f = _arr((k, d))
-    got = dwconv1d_causal_pallas(x, f, block_l=bl, block_d=bd,
-                                 interpret=True)
-    want = ref.dwconv1d_causal_ref(x, f)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-
-
-def test_dwconv1d_step_matches_full():
-    b, l, d, k = 2, 20, 6, 4
-    x = _arr((b, l, d))
-    f = _arr((k, d))
-    full = ref.dwconv1d_causal_ref(x, f)
-    state = jnp.zeros((b, k - 1, d))
-    outs = []
-    for t in range(l):
-        state, y = ref.dwconv1d_step_ref(state, x[:, t], f)
-        outs.append(y)
-    np.testing.assert_allclose(jnp.stack(outs, 1), full, rtol=1e-5,
-                               atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -223,22 +186,6 @@ def test_dwconv2d_linearity(c, hf, s, seed):
     rhs = (2.0 * dwconv2d_pallas(x, f, stride=s, interpret=True)
            + 3.0 * dwconv2d_pallas(y, f, stride=s, interpret=True))
     np.testing.assert_allclose(lhs, rhs, rtol=1e-4, atol=1e-4)
-
-
-@settings(max_examples=15, deadline=None)
-@given(seed=st.integers(0, 2**31 - 1), shift=st.integers(1, 3))
-def test_dwconv1d_shift_equivariance(seed, shift):
-    """Causal depthwise conv commutes with time shift (zero boundary)."""
-    r = np.random.default_rng(seed)
-    b, l, d, k = 1, 24, 4, 3
-    x = jnp.asarray(r.normal(size=(b, l, d)).astype(np.float32))
-    f = jnp.asarray(r.normal(size=(k, d)).astype(np.float32))
-    xs = jnp.pad(x, ((0, 0), (shift, 0), (0, 0)))[:, :l]
-    y = dwconv1d_causal_pallas(x, f, block_l=8, block_d=4, interpret=True)
-    ys = dwconv1d_causal_pallas(xs, f, block_l=8, block_d=4, interpret=True)
-    np.testing.assert_allclose(
-        ys[:, shift:], y[:, : l - shift], rtol=1e-4, atol=1e-4
-    )
 
 
 @settings(max_examples=10, deadline=None)
