@@ -24,6 +24,10 @@ executables:
   the channel-minor array and XLA relays out whatever arrives.
   ``lowering.input_in_place`` / ``lowering.input_relayout`` count the two
   at trace time, once per build;
+* a ``fused3`` kernel expands a group of input rows per GEMM where they
+  collapse for free, else one row per GEMM (``plan_expand_rows``);
+  ``fused3.expand_grouped`` / ``fused3.expand_per_row`` count the two at
+  trace time, one per ``fused3`` segment;
 * a chain's residual rides in its last kernel pass or is added after it
   as a separate op; ``lowering.residual_in_kernel`` /
   ``lowering.residual_separate`` count the two at trace time, one per
@@ -64,6 +68,7 @@ from repro.kernels.policy import DEFAULT_POLICY, KernelPolicy
 from repro.kernels.se_epilogue import dw_se_pallas
 from repro.kernels.separable_fused import (BATCH_LANES,
                                            batch_minor_kernel_model,
+                                           plan_expand_rows,
                                            separable_fused_batch_minor,
                                            separable_fused_pallas)
 from repro.runtime import failures, faultinject, telemetry
@@ -182,6 +187,11 @@ def _run_fused(seg, stages, params, y, res, *, impl, interpret,
     elif pads is not None:
         telemetry.count("lowering.halo_padded")
         y = ops.pad_same(y, d.hf, d.wf, d.stride)
+    if expand_w is not None:
+        ho, wo = d.out_dims(h, w)
+        telemetry.count("fused3.expand_grouped"
+                        if plan_expand_rows(h, w, ho * wo, halo) > 1
+                        else "fused3.expand_per_row")
     return separable_fused_pallas(
         y, dw_f, pw_w, dw_b, pw_b, res,
         expand_w=expand_w, expand_activation=expand_act,

@@ -46,11 +46,14 @@ contribution is exactly zero. Row padding (when ``slab_h`` does not divide
 Expand-on-the-fly (the 3-stage V2 chain, DESIGN.md §5): with ``expand_w``
 ``(Ci, C)`` given, the kernel's input is the RAW ``Ci``-channel tensor and
 each reduction step first computes its expanded-channel slab, one GEMM per
-input row — ``x_window[h] (Wiu, Ci) @ expand_w[:, k*Cb:(k+1)*Cb]`` — into
-VMEM fp32 scratch, applies the expand activation, and feeds that
-slab to the DW shift-and-FMA in place of the streamed input.  Neither the
-expanded tensor (``B*Hi*Wi*C`` — 6x the input at the usual expansion
-factor) nor the DW output ever exists in HBM.  Restriction: the expansion
+group of R input rows — ``x[h:h+R] (R*W, Ci) @ expand_w[:, k*Cb:(k+1)*Cb]``
+— into VMEM fp32 scratch, applies the expand activation, and feeds that
+slab to the DW shift-and-FMA in place of the streamed input.  R > 1 only
+where the rows collapse into GEMM rows for free (:func:`plan_expand_rows`:
+the unpadded image, its width on the fp32 sublane tile); elsewhere R = 1,
+one ``(Wiu, Ci)`` GEMM per row.  Neither the expanded tensor
+(``B*Hi*Wi*C`` — 6x the input at the usual expansion factor) nor the DW
+output ever exists in HBM.  Restriction: the expansion
 must be bias-free, because the SAME halo is zero in the RAW input's
 channels — the kernel writes zeros where a pad pixel's expansion would go
 (or expands the zero pixels the wrapper padded in), and a bias would make
@@ -111,13 +114,36 @@ def _staged(has_expand: bool, stride: int, pads) -> bool:
     return has_expand or stride > 1 or pads is not None
 
 
+#: Rows of an fp32 (8, 128) tile: rows of the image collapse into GEMM rows
+#: without a relayout when their width is a multiple of it.
+_F32_SUBLANES = 8
+
+
+def plan_expand_rows(x_rows: int, x_cols: int, dw_pixels: int,
+                     pads) -> int:
+    """Input rows R that one expand GEMM of the fused3 kernel takes.
+
+    Where the kernel reads the unpadded image (``pads``: the SAME halo made
+    in VMEM, one row slab) and its width ``x_cols`` is a multiple of the
+    fp32 sublane tile, ``x[h:h+R]`` collapses to ``(R*x_cols, Ci)`` for
+    free: R is then the largest divisor of ``x_rows`` whose expanded value
+    ``(R*x_cols, Cb)`` is no larger than the DW intermediate ``(dw_pixels,
+    Cb)`` the kernel models already count.  Elsewhere R = 1: one GEMM per
+    input row, since collapsing a window off the tile is a relayout."""
+    if pads is None or x_cols % _F32_SUBLANES:
+        return 1
+    return max((r for r in range(1, x_rows + 1)
+                if x_rows % r == 0 and r * x_cols <= dw_pixels), default=1)
+
+
 def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
                        co: int, hf: int, wf: int, stride: int,
                        block_c: int, block_co: int, slab_h: int,
                        itemsize: int, out_itemsize: int,
                        has_expand: bool, has_dw_bias: bool,
                        has_pw_bias: bool, has_residual: bool,
-                       pads=None) -> KernelModel:
+                       pads=None,
+                       expand_rows: Optional[int] = None) -> KernelModel:
     """The exact grid/BlockSpec geometry ``separable_fused_pallas`` lowers
     to at these blocks — the single source of truth consumed by BOTH the
     kernel (specs built from this model) and the static analyzer
@@ -128,7 +154,8 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
     Shapes are the PADDED shapes the kernel hands to ``pl.pallas_call``
     after channel/Co/row padding.  With ``pads`` (the SAME padding made in
     VMEM, one row slab) the input is the unpadded image, one whole block
-    per image.
+    per image.  ``expand_rows`` is the fused3 kernel's expand group
+    (None: :func:`plan_expand_rows`'s).
     """
     cb, cob = block_c, block_co
     sh = min(slab_h, ho)
@@ -188,6 +215,12 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
     out_ref = BlockRef("out", (b, ho_p, wo, cop), (1, sh, wo, cob),
                        lambda i, s, j, k: (i, s, 0, j), out_itemsize)
     reshapes = [((sh, wo, cb), (sh * wo, cb))]
+    if has_expand:
+        _, x_rows, x_cols, _ = x_ref.block_shape
+        r = expand_rows or plan_expand_rows(x_rows, x_cols, sh * wo, pads)
+        assert x_rows % r == 0, (x_ref.block_shape, r)
+        if r > 1:
+            reshapes.append(((r, x_cols, c_in), (r * x_cols, c_in)))
     return KernelModel(
         name="separable_fused3" if has_expand else "separable_fused2",
         grid=(b, n_slabs, cop // cob, nk),
@@ -205,8 +238,9 @@ def fused_kernel_model(*, b: int, ho: int, wo: int, c_in: int, c: int,
 
 def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
                   dw_activation, activation, has_exp: bool,
-                  expand_activation, has_dwb: bool, has_pwb: bool,
-                  has_res: bool, out_dtype, pads):
+                  expand_activation, expand_rows: Optional[int],
+                  has_dwb: bool, has_pwb: bool, has_res: bool, out_dtype,
+                  pads):
     """refs = (x, [expand_w,] f, [dw_bias,] w, [pw_bias,] [residual,] out,
     acc, [stage]).
 
@@ -217,7 +251,9 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     pw_bias (1, Cob); residual (1, slab_h, Wo, Cob); out (1, slab_h, Wo,
     Cob); acc VMEM scratch (slab_h*Wo, Cob) fp32; stage: the fp32
     lane-chunked DW input window (the expanded slab, or x when strided or
-    when the kernel makes the SAME halo — ``taps.py``).
+    when the kernel makes the SAME halo — ``taps.py``).  ``expand_rows``:
+    the input rows one expand GEMM takes (None: :func:`plan_expand_rows`'s
+    at these blocks, as ``fused_kernel_model`` records it).
     """
     it = iter(refs)
     x_ref = next(it)
@@ -244,20 +280,25 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
 
     if ew_ref is not None:
         # --- expand stage: this step's expanded-channel slab, on the fly ---
-        # one (Wiu, Ci) @ (Ci, Cb) GEMM per input row into the fp32 stage;
-        # never in HBM.  Row by row, because collapsing (slab_hi, Wiu) into
-        # GEMM rows is a relayout when Wiu is off the sublane tile.
+        # one (R*Wiu, Ci) @ (Ci, Cb) GEMM per group of R input rows into the
+        # fp32 stage; never in HBM.  R = 1 (row by row) unless the rows
+        # collapse into GEMM rows without a relayout (plan_expand_rows).
         ew = ew_ref[...].astype(jnp.float32)
         prec = contract_precision(x_ref.dtype)
+        r = expand_rows or plan_expand_rows(x_rows, x_cols, slab_h * wo,
+                                            pads)
 
-        def expand_row(h, carry):
-            ex = jnp.dot(x_ref[0, h].astype(jnp.float32), ew,
+        def expand(g, carry):
+            h = g * r
+            xs = x_ref[0, pl.ds(h, r)].astype(jnp.float32)
+            ex = jnp.dot(xs.reshape(r * x_cols, xs.shape[-1]), ew,
                          preferred_element_type=jnp.float32, precision=prec)
-            taps.stage(stage_ref, _epilogue(ex, None, expand_activation),
-                       row=h + top, col=left)
+            taps.stage(stage_ref, _epilogue(ex, None, expand_activation)
+                       .reshape(r, x_cols, cb),
+                       row=pl.ds(h + top, r), col=left)
             return carry
 
-        jax.lax.fori_loop(0, x_rows, expand_row, 0)
+        jax.lax.fori_loop(0, x_rows // r, expand, 0)
     elif stage_ref is not None:
         taps.stage_input(stage_ref, x_ref, top, left)
     if pads is not None:
@@ -300,7 +341,7 @@ def _fused_kernel(*refs, hf: int, wf: int, stride: int, nk: int,
     jax.jit,
     static_argnames=("stride", "dw_activation", "activation",
                      "expand_activation", "block_c", "block_co", "slab_h",
-                     "interpret", "out_dtype", "pads"),
+                     "interpret", "out_dtype", "pads", "expand_rows"),
 )
 def separable_fused_pallas(
     x: jax.Array,
@@ -321,6 +362,7 @@ def separable_fused_pallas(
     interpret: bool = False,
     out_dtype: Optional[str] = None,
     pads: Optional[blocking.Pads] = None,
+    expand_rows: Optional[int] = None,
 ) -> jax.Array:
     """Fused DW+PW block. x (B,Hi,Wi,C); dw_f (Hf,Wf,C); pw_w (C,Co)
     [+ dw_bias (C,), pw_bias (Co,), residual (B,Ho,Wo,Co)] -> (B,Ho,Wo,Co).
@@ -339,7 +381,9 @@ def separable_fused_pallas(
     unpadded ``x`` (``blocking.same_pads``) — makes the kernel apply it in
     VMEM; the plan must then be one row slab (``blocking.kernel_pads``).
     Without ``pads`` the geometry is VALID and a SAME caller pads ``x``
-    first (``ops.pad_same``).  Block shapes not given explicitly come from
+    first (``ops.pad_same``).  ``expand_rows`` sets the input rows of one
+    expand GEMM (a divisor of the rows the kernel reads); None takes
+    :func:`plan_expand_rows`'s.  Block shapes not given explicitly come from
     :func:`repro.kernels.blocking.plan_separable` (or ``plan_separable3``
     with expand); raises ValueError when even the minimal plan exceeds the
     VMEM budget (callers should have consulted the planner and taken a
@@ -438,7 +482,7 @@ def separable_fused_pallas(
         itemsize=x.dtype.itemsize, out_itemsize=odt.itemsize,
         has_expand=expand_w is not None, has_dw_bias=dw_bias is not None,
         has_pw_bias=pw_bias is not None, has_residual=residual is not None,
-        pads=pads,
+        pads=pads, expand_rows=expand_rows,
     )
     inputs = [x]
     if expand_w is not None:
@@ -460,8 +504,9 @@ def separable_fused_pallas(
         _fused_kernel, hf=hf, wf=wf, stride=stride, nk=nk,
         dw_activation=dw_activation, activation=activation,
         has_exp=expand_w is not None, expand_activation=expand_activation,
-        has_dwb=dw_bias is not None, has_pwb=pw_bias is not None,
-        has_res=residual is not None, out_dtype=odt, pads=pads,
+        expand_rows=expand_rows, has_dwb=dw_bias is not None,
+        has_pwb=pw_bias is not None, has_res=residual is not None,
+        out_dtype=odt, pads=pads,
     )
     stage = taps.stage_shapes((slab_hi, wiu, cb),
                               _staged(expand_w is not None, stride, pads))

@@ -72,6 +72,7 @@ BLOCKS = [
     ("efficientnet_lite0_spec", 1, "fp32"),  # fusedmb s2
     ("mobilenet_v2_spec", 3, "bf16"),        # fused3 s2, bf16 stream
     ("mobilenet_v2_spec", 2, "bf16"),        # fused3 s1 + residual, bf16
+    ("mobilenet_v2_spec", 1, "bf16"),        # fused3 s2 at 112, 28-row expand
     ("efficientnet_lite0_spec", 11, "bf16"),  # fused3 5x5 s2, pads (1, 2)
     ("efficientnet_lite0_spec", 12, "fp32"),  # fused3 5x5 s1 at 7x7
 ]
